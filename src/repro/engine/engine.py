@@ -14,6 +14,14 @@ The single entry point every launcher and benchmark builds on:
 continuous-batching step function `repro.serving`'s scheduler drives, where
 every slot gathers its own table row and guidance scale (DESIGN.md §9).
 
+Weights are program arguments. `launch.sample.build_engine` binds the param
+tree into each eps callable with `jax.tree_util.Partial`, whose bound
+arguments are pytree leaves, and every jitted program here takes the bundle
+of callables (`SamplerEngine.nets`) as its first argument. So the weights
+reach XLA as inputs, never as HLO constants: the lowered program does not
+grow with the model, HBM holds one copy of the weights, and compile-cache
+keys do not hash weight values.
+
 `build` compiles the solver's weight table (registry-driven — see
 `compiler.py`), wraps the eps-network into the table's prediction type, and
 jits one `unipc_sample_scan` over the result. Conditional sampling (the
@@ -30,11 +38,13 @@ paper's Table 9 setting) is fused into that same scan:
 from __future__ import annotations
 
 from dataclasses import dataclass, replace as dc_replace
+from functools import partial
 from typing import Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.tree_util import Partial
 
 from ..core.coeffs import SolverTable, eval_cost_rows, stack_step_rows
 from ..core.unipc import step_fn_over_rows, unipc_sample_scan
@@ -72,27 +82,25 @@ class CacheSpec:
 class StepProgram:
     """A compiled per-slot step program — what the serving scheduler drives.
 
-    step(state, idx[, g]) -> state advances every slot by one table row:
-    `state = (x, E)` with x (B, *sample) and E the (K+1, B, *sample) eval
-    ring — or `(x, E, C)` for feature-reuse programs, C the (B, *cache)
-    deep-feature cache that must live (and be donated) with the rest of the
-    slot state (DESIGN.md §12) — `idx` (B,) int32 the per-slot row index
-    (0 = init row; idle slots park there), and `g` (B,) float32 the per-slot
-    guidance scale (only for cfg-enabled programs). Slot batches are sharded
-    over the data axis via the active `parallel.sharding` rules (SERVE_RULES
-    on the mesh; a no-op single-device), so the same tick loop runs
-    everywhere. One batched model eval per call — a request admitted at tick
-    tau and stepped through rows 0..n_rows-1 reproduces the uniform
-    `build()` scan for its own (solver, order, nfe, seed, cfg-scale)
-    exactly.
+    step_flight(state, meta[, g, extras]) -> (state, meta, done) advances
+    every busy slot by one table row: `state = (x, E)` with x (B, *sample)
+    and E the (K+1, B, *sample) eval ring — or `(x, E, C)` for feature-reuse
+    programs, C the (B, *cache) deep-feature cache that must live (and be
+    donated) with the rest of the slot state (DESIGN.md §12) — and `g` (B,)
+    float32 the per-slot guidance scale (only for cfg-enabled programs).
+    Slot batches are sharded over the data axis via the active
+    `parallel.sharding` rules (SERVE_RULES on the mesh; a no-op
+    single-device), so the same tick loop runs everywhere. One batched model
+    eval per call — a request admitted at tick tau and stepped through rows
+    0..n_rows-1 reproduces the uniform `build()` scan for its own (solver,
+    order, nfe, seed, cfg-scale) exactly.
 
-    step_flight(state, meta[, g, extras]) -> (state, meta, done) is the
-    async-serving variant (DESIGN.md §13): the per-slot bookkeeping lives
-    on device as `meta`, a (4, B) int32 array of [row, offset, budget, busy]
-    rows (`init_meta`). The program derives each slot's table index from its
-    own counters (`offset + row` while busy, the parked init row otherwise),
-    advances them, and emits the per-slot `done` mask — the tick a busy slot
-    executes its last budgeted row. The mask is a coded int32 per slot
+    The per-slot bookkeeping lives on device as `meta` (DESIGN.md §13), a
+    (4, B) int32 array of [row, offset, budget, busy] rows (`init_meta`).
+    The program derives each slot's table index from its own counters
+    (`offset + row` while busy, the parked init row 0 otherwise), advances
+    them, and emits the per-slot `done` mask — the tick a busy slot executes
+    its last budgeted row. The mask is a coded int32 per slot
     (`compiler.DONE_IDLE` / `DONE_OK` / `DONE_NONFINITE`): completion folds
     an on-device finite-check of the slot's latent, so the serving layer
     learns at emission — not from a host-side scan — whether the request's
@@ -101,7 +109,11 @@ class StepProgram:
     is what lets the serving scheduler keep several ticks in flight.
     """
 
-    step: Callable
+    # the jitted program: flight(nets, state, meta, g, extras). `nets` is
+    # the engine's eps bundle (`SamplerEngine.nets`) — the weights ride in
+    # as an argument; `step_flight` binds it for callers
+    flight: Callable
+    nets: dict
     n_rows: int          # total table rows (single plan: ticks per request)
     table: SolverTable   # single-plan programs; first tier's table for banks
     spec: EngineSpec
@@ -115,9 +127,9 @@ class StepProgram:
     # 1.0 everywhere without caching, cache_block/n_blocks on reuse rows.
     cache: Optional[CacheSpec] = None
     row_cost: Optional[np.ndarray] = None
-    # the on-device-bookkeeping step (same compiled math as `step`, plus the
-    # meta counters and done mask); always built by `_step_program`
-    step_flight: Optional[Callable] = None
+
+    def step_flight(self, state, meta, g=None, extras=None):
+        return self.flight(self.nets, state, meta, g, extras)
 
     def resolve_tier(self, tier: Optional[str]) -> Tuple[int, int]:
         """(row_offset, rows_to_run) for a request's tier tag. Single-plan
@@ -195,6 +207,10 @@ class SamplerEngine:
     cache_spec:  the cache-state contract matching `eps_cached`; its `block`
                  is handshaken against every spec's `cache_block` exactly
                  like `eval_dtype`.
+
+    The eps callables may be plain functions (analytic models) or
+    `jax.tree_util.Partial`s binding a param tree; `nets` hands them to the
+    compiled programs as one argument.
     """
 
     schedule: NoiseSchedule
@@ -209,6 +225,16 @@ class SamplerEngine:
     # quantized for (`launch.sample.build_engine(quant=...)` sets it)
     quant: str = "none"
 
+    def nets(self) -> dict:
+        """The wired eps callables as one pytree argument: a Partial's
+        bound params are leaves (traced inputs of the program), a plain
+        function is a leafless Partial. Never re-wrap a Partial — the outer
+        one would hide the inner's leaves and bake them back in."""
+        fns = {"eps": self.eps, "eps_stacked": self.eps_stacked,
+               "eps_uncond": self.eps_uncond, "eps_cached": self.eps_cached}
+        return {k: f if isinstance(f, Partial) else Partial(f)
+                for k, f in fns.items() if f is not None}
+
     # -- table ---------------------------------------------------------------
     def compile(self, spec: EngineSpec,
                 table: Optional[SolverTable] = None) -> SolverTable:
@@ -221,11 +247,14 @@ class SamplerEngine:
         return apply_model_cols(tab, spec)
 
     # -- model ---------------------------------------------------------------
-    def model_fn(self, spec: EngineSpec, tab: SolverTable) -> Callable:
+    def model_fn(self, spec: EngineSpec, tab: SolverTable,
+                 nets: Optional[dict] = None) -> Callable:
         """Wrap the eps-net into the table's prediction type, consuming the
         per-eval model columns the table carries (g, tq). Any further keyword
         arguments (per-slot conditioning from a StepProgram's extras, e.g.
-        class ids) pass through to the eps-net.
+        class ids) pass through to the eps-net. `nets` is the (traced)
+        `nets()` bundle inside a compiled program; None uses the engine's
+        own callables.
 
         `spec.eval_dtype` is the network-eval precision boundary (DESIGN.md
         §11): the state is cast down on the way into the eps-net and the
@@ -234,33 +263,15 @@ class SamplerEngine:
         network itself to *compute* in bf16 the model config's activation
         dtype must match — `launch.sample.build_engine(eval_dtype=...)`
         wires both ends.)"""
-        spec = spec.resolve()
-        if spec.eval_dtype != self.eval_dtype:
-            raise ValueError(
-                f"spec.eval_dtype={spec.eval_dtype!r} but this engine's "
-                f"eps-net was wired for {self.eval_dtype!r}; pass the same "
-                f"eval_dtype to build_engine and the EngineSpec")
-        if spec.quant != self.quant:
-            raise ValueError(
-                f"spec.quant={spec.quant!r} but this engine's eps-net was "
-                f"wired for {self.quant!r}; the quantized param tree is "
-                f"baked into the net — pass the same quant to build_engine "
-                f"and the EngineSpec")
+        spec = self.check_wiring(spec, tab)
+        nets = self.nets() if nets is None else nets
         if spec.cache_block:
-            return self._cached_model_fn(spec, tab)
-        if "cache_reuse" in (tab.model_cols or {}):
-            raise ValueError(
-                "this table carries a cache_reuse column (a cached plan) but "
-                "spec.cache_block=0; build the engine and spec with the "
-                "plan's cache_block so its shallow steps actually reuse the "
-                "feature cache instead of silently paying full evals")
+            return self._cached_model_fn(spec, tab, nets["eps_cached"])
         if spec.cfg_scale:
-            if self.eps_stacked is None:
-                raise ValueError("cfg_scale != 0 needs eps_stacked (a 2B "
-                                 "cond+uncond batched eps-net)")
-            eps = cfg_model_fused(self.eps_stacked)   # (x, t, g, **extra)
+            eps = cfg_model_fused(nets["eps_stacked"])  # (x, t, g, **extra)
         else:
-            eps = lambda x, t, g=None, **extra: self.eps(x, t, **extra)
+            eps_c = nets["eps"]
+            eps = lambda x, t, g=None, **extra: eps_c(x, t, **extra)
 
         schedule = self.schedule
         if spec.eval_dtype != "float32":
@@ -284,25 +295,53 @@ class SamplerEngine:
 
         return model
 
-    def _cached_model_fn(self, spec: EngineSpec, tab: SolverTable) -> Callable:
+    def check_wiring(self, spec: EngineSpec, tab: SolverTable) -> EngineSpec:
+        """The spec <-> wired-net handshakes `model_fn` relies on; raised
+        when a program is built, before anything is traced."""
+        spec = spec.resolve()
+        if spec.eval_dtype != self.eval_dtype:
+            raise ValueError(
+                f"spec.eval_dtype={spec.eval_dtype!r} but this engine's "
+                f"eps-net was wired for {self.eval_dtype!r}; pass the same "
+                f"eval_dtype to build_engine and the EngineSpec")
+        if spec.quant != self.quant:
+            raise ValueError(
+                f"spec.quant={spec.quant!r} but this engine's eps-net was "
+                f"wired for {self.quant!r}; the quantized param tree is "
+                f"bound into the net — pass the same quant to build_engine "
+                f"and the EngineSpec")
+        if spec.cache_block:
+            if self.eps_cached is None or self.cache_spec is None:
+                raise ValueError(
+                    f"spec.cache_block={spec.cache_block} but this engine has "
+                    f"no cached eps-net; wire one with "
+                    f"build_engine(cache_block={spec.cache_block})")
+            if spec.cache_block != self.cache_spec.block:
+                raise ValueError(
+                    f"spec.cache_block={spec.cache_block} but the engine's "
+                    f"cached eps-net was wired for cache boundary "
+                    f"{self.cache_spec.block}; the boundary is baked into "
+                    f"the compiled program — pass the same cache_block to "
+                    f"build_engine and the EngineSpec")
+            return spec
+        if "cache_reuse" in (tab.model_cols or {}):
+            raise ValueError(
+                "this table carries a cache_reuse column (a cached plan) but "
+                "spec.cache_block=0; build the engine and spec with the "
+                "plan's cache_block so its shallow steps actually reuse the "
+                "feature cache instead of silently paying full evals")
+        if spec.cfg_scale and self.eps_stacked is None:
+            raise ValueError("cfg_scale != 0 needs eps_stacked (a 2B "
+                             "cond+uncond batched eps-net)")
+        return spec
+
+    def _cached_model_fn(self, spec: EngineSpec, tab: SolverTable,
+                         eps_cached: Callable) -> Callable:
         """The feature-reuse model wrapper: (x, t, cache=..., cache_reuse=...,
         tq=..., **extra) -> (prediction, cache'). `cache_reuse` arrives from
         the table's `cache_reuse` model column when the plan schedules
         shallow steps; a plain registry table has no such column and every
         eval runs full (reuse = 0) — the bit-identity parity path."""
-        if self.eps_cached is None or self.cache_spec is None:
-            raise ValueError(
-                f"spec.cache_block={spec.cache_block} but this engine has no "
-                f"cached eps-net; wire one with "
-                f"build_engine(cache_block={spec.cache_block})")
-        if spec.cache_block != self.cache_spec.block:
-            raise ValueError(
-                f"spec.cache_block={spec.cache_block} but the engine's "
-                f"cached eps-net was wired for cache boundary "
-                f"{self.cache_spec.block}; the boundary is baked into the "
-                f"compiled program — pass the same cache_block to "
-                f"build_engine and the EngineSpec")
-        eps_cached = self.eps_cached
         schedule = self.schedule
         if spec.eval_dtype != "float32":
             eval_dtype = jnp.dtype(spec.eval_dtype)
@@ -332,18 +371,17 @@ class SamplerEngine:
         Pass `table` (from a prior `compile`) to skip recompiling it."""
         spec = spec.resolve()
         tab = table if table is not None else self.compile(spec)
-        model = self.model_fn(spec, tab)
-        if spec.cache_block:
-            cache_spec = self.cache_spec
+        self.check_wiring(spec, tab)
+        cache_spec = self.cache_spec if spec.cache_block else None
 
-            def run(x_T):
-                return unipc_sample_scan(
-                    model, x_T, tab, fused_update=spec.fused_update,
-                    cache0=cache_spec.zeros(x_T.shape[0]))
-        else:
-            run = lambda x_T: unipc_sample_scan(
-                model, x_T, tab, fused_update=spec.fused_update)
-        return jax.jit(run) if jit else run
+        def run(nets, x_T):
+            cache0 = (cache_spec.zeros(x_T.shape[0]) if cache_spec is not None
+                      else None)
+            return unipc_sample_scan(
+                self.model_fn(spec, tab, nets), x_T, tab,
+                fused_update=spec.fused_update, cache0=cache0)
+
+        return partial(jax.jit(run) if jit else run, self.nets())
 
     def build_step(self, spec: EngineSpec, jit: bool = True,
                    table: Optional[SolverTable] = None,
@@ -431,7 +469,7 @@ class SamplerEngine:
                     f"column) but the bank specs have cache_block=0; set "
                     f"cache_block on every tier spec (and the engine) to "
                     f"serve it")
-        model = self.model_fn(spec0, tab0)
+        self.check_wiring(spec0, tab0)
         profs, step_tabs = [], {}
         for name, (s, t) in items.items():
             if uses_cfg:
@@ -453,9 +491,6 @@ class SamplerEngine:
         rows_np, spans = stack_step_rows(step_tabs)
         n_rows = len(rows_np["t"])
         rows = {k: jnp.asarray(v, jnp.float32) for k, v in rows_np.items()}
-        core_step = step_fn_over_rows(model, rows, sign=tab0.sign,
-                                      fused_update=spec0.fused_update,
-                                      cached=cached)
         prof = (jnp.asarray(np.concatenate(profs), jnp.float32)
                 if uses_cfg else None)
         row_cost = (eval_cost_rows(rows_np, cache_block=spec0.cache_block,
@@ -471,26 +506,22 @@ class SamplerEngine:
             C = state[2]
             return x, E, shard(C, "batch", *([None] * (C.ndim - 1)))
 
-        def _apply(state, idx, g, extras):
-            state = _shard_state(*state)
-            kw = dict(extras) if extras else {}
-            if uses_cfg:
-                gs = (jnp.full(idx.shape, float(spec0.cfg_scale), jnp.float32)
-                      if g is None else jnp.asarray(g, jnp.float32))
-                kw["g"] = gs * prof[jnp.clip(idx, 0, n_rows - 1)]
-            state = core_step(state, idx, model_kwargs=kw or None)
-            return _shard_state(*state)
-
-        def step(state, idx, g=None, extras=None):
-            return _apply(state, idx, g, extras)
-
-        def step_flight(state, meta, g=None, extras=None):
+        def flight(nets, state, meta, g=None, extras=None):
             # on-device bookkeeping (DESIGN.md §13): the slot's table index
             # is derived from its own counters, never shipped from the host
             row, off, budget, busy = meta
             live = busy > 0
             idx = jnp.where(live, off + row, 0).astype(jnp.int32)
-            state = _apply(state, idx, g, extras)
+            kw = dict(extras) if extras else {}
+            if uses_cfg:
+                gs = (jnp.full(idx.shape, float(spec0.cfg_scale), jnp.float32)
+                      if g is None else jnp.asarray(g, jnp.float32))
+                kw["g"] = gs * prof[jnp.clip(idx, 0, n_rows - 1)]
+            core_step = step_fn_over_rows(
+                self.model_fn(spec0, tab0, nets), rows, sign=tab0.sign,
+                fused_update=spec0.fused_update, cached=cached)
+            state = _shard_state(*core_step(_shard_state(*state), idx,
+                                            model_kwargs=kw or None))
             row = row + 1
             done = live & (row >= budget)
             live = live & ~done
@@ -507,22 +538,18 @@ class SamplerEngine:
             return state, meta, flag_done(done, state[0])
 
         if jit:
-            # donate the slot state (arg 0): the tick's (x, E) update writes
+            # donate the slot state (arg 1): the tick's (x, E) update writes
             # into the previous tick's buffers instead of fresh HBM — safe
             # because every caller replaces its state reference with the
             # step's return value (bit-identity pinned in tests/test_serving).
             # For cached programs the feature cache C rides in the same
             # donated tuple: it is per-slot trajectory state exactly like the
             # eval ring, so it must live (and be recycled) with it. The
-            # flight variant additionally donates the (tiny) meta counters,
-            # which live and recycle with the state across in-flight ticks.
-            if donate:
-                step = jax.jit(step, donate_argnums=(0,))
-                step_flight = jax.jit(step_flight, donate_argnums=(0, 1))
-            else:
-                step = jax.jit(step)
-                step_flight = jax.jit(step_flight)
-        return StepProgram(step=step, step_flight=step_flight, n_rows=n_rows,
+            # (tiny) meta counters (arg 2) live and recycle with the state
+            # across in-flight ticks. The weights (arg 0) are never donated.
+            flight = jax.jit(flight,
+                             donate_argnums=(1, 2) if donate else ())
+        return StepProgram(flight=flight, nets=self.nets(), n_rows=n_rows,
                            table=tab0, spec=spec0, uses_cfg=uses_cfg,
                            ring=rows_np["w_pred"].shape[-1] + 1,
                            tiers=dict(spans) if tiers else None,

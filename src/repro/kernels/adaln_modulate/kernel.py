@@ -20,11 +20,13 @@ Two kernels, each one pass over the activation:
 * `gate_residual(resid, gate, y)` — `resid + gate * y`, the adaLN-zero gated
   residual re-entry: three reads, one write, no intermediate.
 
-Layout: x/resid/y (B, T, D); shift/scale/gate (B, D) broadcast over tokens.
-Grid is (B, T tiles); D lives fully inside the block (DiT widths are <= a
-few K lanes, far under VMEM). D is padded to the 128-lane boundary by ops.py
-(masked in the LN reduction, garbage lanes sliced off), T to the token-tile
-boundary (rows sliced off).
+Layout: x/resid/y (B, T, D); shift/scale/gate (B, D) broadcast over tokens,
+viewed as (B, 1, D) so each grid row reads a (1, 1, D) block — a TPU block's
+last two dims must be (8k, 128k) or span the array, which a (1, D) block of
+a (B, D) array does not at B > 1. Grid is (B, T tiles); D lives fully inside
+the block (DiT widths are <= a few K lanes, far under VMEM). D is padded to
+the 128-lane boundary by ops.py (masked in the LN reduction, garbage lanes
+sliced off), T to the token-tile boundary (rows sliced off).
 """
 
 from __future__ import annotations
@@ -51,9 +53,9 @@ def _modulate_kernel(x_ref, sh_ref, sc_ref, o_ref, *, d_true, eps):
         cen = jnp.where(mask, cen, 0.0)
     var = jnp.sum(cen * cen, axis=-1, keepdims=True) / d_true
     y = cen * jax.lax.rsqrt(var + eps)
-    sc = sc_ref[0].astype(jnp.float32)                     # (Dp,)
+    sc = sc_ref[0].astype(jnp.float32)                     # (1, Dp)
     sh = sh_ref[0].astype(jnp.float32)
-    o_ref[0] = (y * (1.0 + sc)[None, :] + sh[None, :]).astype(o_ref.dtype)
+    o_ref[0] = (y * (1.0 + sc) + sh).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("d_true", "eps", "blk_t",
@@ -70,20 +72,20 @@ def adaln_modulate(x, shift, scale, *, d_true, eps=1e-5,
         grid=(B, T // blk_t),
         in_specs=[
             pl.BlockSpec((1, blk_t, Dp), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, Dp), lambda b, i: (b, 0)),
-            pl.BlockSpec((1, Dp), lambda b, i: (b, 0)),
+            pl.BlockSpec((1, 1, Dp), lambda b, i: (b, 0, 0)),
+            pl.BlockSpec((1, 1, Dp), lambda b, i: (b, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, blk_t, Dp), lambda b, i: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((B, T, Dp), x.dtype),
         interpret=interpret,
-    )(x, shift, scale)
+    )(x, shift.reshape(B, 1, Dp), scale.reshape(B, 1, Dp))
 
 
 def _gate_res_kernel(r_ref, g_ref, y_ref, o_ref):
     r = r_ref[0].astype(jnp.float32)
     y = y_ref[0].astype(jnp.float32)
-    g = g_ref[0].astype(jnp.float32)
-    o_ref[0] = (r + g[None, :] * y).astype(o_ref.dtype)
+    g = g_ref[0].astype(jnp.float32)                       # (1, Dp)
+    o_ref[0] = (r + g * y).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("blk_t", "interpret"))
@@ -97,10 +99,10 @@ def gate_residual(resid, gate, y, *, blk_t=DEFAULT_BLOCK_T, interpret=True):
         grid=(B, T // blk_t),
         in_specs=[
             pl.BlockSpec((1, blk_t, Dp), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, Dp), lambda b, i: (b, 0)),
+            pl.BlockSpec((1, 1, Dp), lambda b, i: (b, 0, 0)),
             pl.BlockSpec((1, blk_t, Dp), lambda b, i: (b, i, 0)),
         ],
         out_specs=pl.BlockSpec((1, blk_t, Dp), lambda b, i: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((B, T, Dp), resid.dtype),
         interpret=interpret,
-    )(resid, gate, y)
+    )(resid, gate.reshape(B, 1, Dp), y)

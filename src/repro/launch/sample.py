@@ -20,6 +20,7 @@ import time
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.tree_util import Partial
 
 from ..checkpoint import ckpt
 from ..configs.registry import get_config
@@ -27,6 +28,7 @@ from ..data.synthetic import class_ids
 from ..diffusion import VPLinear
 from ..engine import EngineSpec, SamplerEngine
 from ..models import api
+from .compile_cache import enable_compile_cache
 
 NULL_CLASS_ID = 1000  # init_dit allocates num_classes + 1 embeddings; the
                       # extra row is the CFG null class
@@ -64,7 +66,11 @@ def build_engine(cfg, params, schedule, batch: int, seed: int = 0,
     given `seed`) before wiring, so every eps branch — stacked CFG, cached —
     routes its dense sites through kernels/quant_matmul. The engine records
     the tier and `model_fn` rejects specs that disagree, exactly like
-    eval_dtype."""
+    eval_dtype.
+
+    Every eps branch is a `jax.tree_util.Partial` binding the (cast or
+    quantized) param tree, so the engine's compiled programs take the
+    weights as an argument instead of baking them in as constants."""
     import dataclasses
 
     if eval_dtype not in ("float32", "bfloat16"):
@@ -98,8 +104,9 @@ def build_engine(cfg, params, schedule, batch: int, seed: int = 0,
     def eps_with(extra):
         # jit so the python-loop reference path gets compiled evals too; the
         # scan path's outer jit simply inlines it
-        return jax.jit(
-            lambda x, t: net(params, x, jnp.asarray(t, jnp.float32), extra))
+        return Partial(jax.jit(
+            lambda p, x, t: net(p, x, jnp.asarray(t, jnp.float32), extra)),
+            params)
 
     def cache_kw(baked=None):
         """(eps_cached, cache_spec) for this wiring — None, None uncached.
@@ -112,11 +119,11 @@ def build_engine(cfg, params, schedule, batch: int, seed: int = 0,
 
         cnet = api.eps_network_cached(cfg, cache_block)
 
-        def eps_cached(x, t, cache, reuse, **extra):
-            return cnet(params, x, jnp.asarray(t, jnp.float32),
+        def eps_cached(p, x, t, cache, reuse, **extra):
+            return cnet(p, x, jnp.asarray(t, jnp.float32),
                         baked if baked is not None else extra, cache, reuse)
 
-        return {"eps_cached": eps_cached,
+        return {"eps_cached": Partial(eps_cached, params),
                 "cache_spec": CacheSpec(shape=dit_cache_shape(cfg),
                                         block=cache_block,
                                         n_blocks=cfg.num_layers,
@@ -130,19 +137,20 @@ def build_engine(cfg, params, schedule, batch: int, seed: int = 0,
                              eval_dtype=eval_dtype, quant=quant)
     null = jnp.full((batch,), NULL_CLASS_ID, jnp.int32)
     if per_request_cond:
-        def eps_cond(x, t, class_ids):
-            return net(params, x, jnp.asarray(t, jnp.float32),
+        def eps_cond(p, x, t, class_ids):
+            return net(p, x, jnp.asarray(t, jnp.float32),
                        {"class_ids": class_ids})
 
-        def eps_stacked(xx, t, class_ids):
+        def eps_stacked(p, xx, t, class_ids):
             ids2 = jnp.concatenate([jnp.asarray(class_ids, jnp.int32),
                                     jnp.full_like(class_ids, NULL_CLASS_ID,
                                                   jnp.int32)])
-            return net(params, xx, jnp.asarray(t, jnp.float32),
+            return net(p, xx, jnp.asarray(t, jnp.float32),
                        {"class_ids": ids2})
 
-        return SamplerEngine(schedule, eps=jax.jit(eps_cond),
-                             eps_stacked=jax.jit(eps_stacked),
+        return SamplerEngine(schedule,
+                             eps=Partial(jax.jit(eps_cond), params),
+                             eps_stacked=Partial(jax.jit(eps_stacked), params),
                              eps_uncond=eps_with({"class_ids": null}),
                              eval_dtype=eval_dtype, quant=quant,
                              **cache_kw())
@@ -294,6 +302,7 @@ def main():
     scale.add_argument("--full", action="store_true")
     ap.add_argument("--ckpt", default=None)
     args = ap.parse_args()
+    enable_compile_cache()
     require_dit_for_cfg(ap, args.arch, args.cfg_scale)
     if args.plan and args.loop:
         ap.error("--plan runs the scan-compiled table; --loop has no "

@@ -30,6 +30,7 @@ import numpy as np
 from ..configs.registry import get_config
 from ..data.synthetic import TokenStream, class_ids, stub_embeds
 from ..models import api
+from .compile_cache import enable_compile_cache
 
 
 def serve(arch: str, *, reduced=True, batch=4, prompt_len=32, gen=32,
@@ -88,7 +89,7 @@ def serve_diffusion(arch: str, *, reduced=True, batch=4, nfe=10, order=3,
                     quant="none", pipeline_depth=2, trace_out=None,
                     metrics_out=None, metrics_every=None,
                     probe_fraction=0.0, probe_ref_nfe=64,
-                    resilience=None, faults=None):
+                    resilience=None, faults=None, report=None):
     """Continuous-batching diffusion serving through the engine's per-slot
     step program (`SamplerEngine.build_step` + `serving.SlotScheduler`):
     `batch` slots, requests admitted the tick a slot frees, per-request
@@ -137,6 +138,11 @@ def serve_diffusion(arch: str, *, reduced=True, batch=4, nfe=10, order=3,
     records per-tier trajectory-discrepancy gauges. All three are off by
     default — the untraced path is byte-for-byte the old serving loop.
     Render the artifacts with `python -m repro.launch.obsreport`.
+
+    `report` (a dict, optional) receives the run's `ServeMetrics`
+    (`metrics`), the AOT compile seconds (`compile_s`) and the compiled
+    step's HLO text (`step_text`), for callers that check the served
+    program itself (chip_smoke.py).
     """
     from ..engine import EngineSpec, default_tier_specs
     from ..diffusion import VPLinear
@@ -275,6 +281,9 @@ def serve_diffusion(arch: str, *, reduced=True, batch=4, nfe=10, order=3,
         metrics_every = 8
     m = run_trace(sched, reqs, snapshot_every=metrics_every,
                   snapshot_log=snapshot_log)
+    if report is not None:
+        report.update(metrics=m, compile_s=compile_s,
+                      step_text=sched.compiled_text())
     if trace_out is not None:
         exported = tracer.export(trace_out)
         print(f"trace: {len(exported['traceEvents'])} events "
@@ -457,6 +466,7 @@ def main():
                        help="reduced CPU-scale config (the default)")
     scale.add_argument("--full", action="store_true")
     args = ap.parse_args()
+    enable_compile_cache()
     from .sample import require_dit_for_cfg
     family = require_dit_for_cfg(ap, args.arch, args.cfg_scale)
     if family != "dit" and (args.arrival_rate is not None or args.trace):

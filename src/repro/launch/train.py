@@ -22,6 +22,7 @@ from ..configs.registry import get_config
 from ..data.synthetic import TokenStream, class_ids, latent_images, stub_embeds
 from ..models import api
 from ..optim import AdamW, warmup_cosine
+from .compile_cache import enable_compile_cache
 
 
 def make_train_step(cfg, objective, opt):
@@ -107,6 +108,7 @@ def main():
     ap.add_argument("--ckpt-every", type=int, default=0)
     ap.add_argument("--log-file", default=None)
     args = ap.parse_args()
+    enable_compile_cache()
     train(args.arch, reduced=not args.full, objective=args.objective,
           steps=args.steps, batch=args.batch, seq=args.seq, lr=args.lr,
           ckpt_dir=args.ckpt_dir, ckpt_every=args.ckpt_every,

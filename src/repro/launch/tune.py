@@ -33,6 +33,7 @@ from ..models import api
 from ..tuning import (SearchConfig, SolverPlan, make_objective,
                       quant_parity_gate, reference_trajectory, save_bank,
                       tune_cached_plan, tune_plan)
+from .compile_cache import enable_compile_cache
 from .sample import build_engine, latent_shape
 
 
@@ -270,6 +271,7 @@ def main() -> None:
                        help="reduced CPU-scale config (the default)")
     scale.add_argument("--full", action="store_true")
     args = ap.parse_args()
+    enable_compile_cache()
     if args.smoke:
         report = smoke(args.arch, nfe=args.nfe, budget=args.budget,
                        train_steps=args.train_steps, seed=args.seed,

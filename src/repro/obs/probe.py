@@ -62,7 +62,7 @@ def build_reference_fn(engine, spec, *, ref_nfe: int = 64,
                           eval_dtype="float32", quant="none",
                           cache_block=0).resolve()
     tab = engine.compile(ref_spec)
-    model = engine.model_fn(ref_spec, tab)
+    engine.check_wiring(ref_spec, tab)
     rows_np = augment_step_rows(tab)
     uses_cfg = bool(ref_spec.cfg_scale)
     if uses_cfg:
@@ -71,14 +71,18 @@ def build_reference_fn(engine, spec, *, ref_nfe: int = 64,
         prof = jnp.asarray(step_guidance_profile(tab, ref_spec), jnp.float32)
         rows_np = {k: v for k, v in rows_np.items() if k != "mc_g"}
     rows = {k: jnp.asarray(v, jnp.float32) for k, v in rows_np.items()}
-    step = step_fn_over_rows(model, rows, sign=float(tab.sign),
-                             fused_update=ref_spec.fused_update)
     n_rows = int(rows["t"].shape[0])
     K = int(rows["w_pred"].shape[-1])
     nominal = float(ref_spec.cfg_scale or 0.0)
 
+    nets = engine.nets()
+
     @jax.jit
-    def run(x_T, g, extras):
+    def run(nets, x_T, g, extras):
+        # the weights are an argument, as in the serving step program
+        step = step_fn_over_rows(engine.model_fn(ref_spec, tab, nets), rows,
+                                 sign=float(tab.sign),
+                                 fused_update=ref_spec.fused_update)
         E0 = jnp.zeros((K + 1,) + x_T.shape, x_T.dtype)
 
         def body(carry, j):
@@ -101,7 +105,7 @@ def build_reference_fn(engine, spec, *, ref_nfe: int = 64,
                 else jnp.float32
             ex[k] = jnp.full((B,), v, dt) if a.ndim == 0 \
                 else jnp.asarray(a, dt)
-        return np.asarray(run(x_T, gv, ex))
+        return np.asarray(run(nets, x_T, gv, ex))
 
     return reference
 

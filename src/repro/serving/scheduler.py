@@ -358,12 +358,15 @@ class SlotScheduler:
                                       wall=True,
                                       help="host ns per tick phase")
                          for p in HOST_PHASES}
+        # the dispatched program takes the engine's eps bundle (the weights)
+        # as its first argument, so they are never compiled in as constants.
         # step_override replaces the dispatched flight step — signature
         # step(state, meta, g, extras) -> (state, meta, done), and the done
         # mask must be consistent with the meta counters (it is verified
         # against the host prediction whenever a completion is consumed)
-        self._flight = (step_override if step_override is not None
-                        else program.step_flight)
+        self._nets = program.nets
+        self._flight = (program.flight if step_override is None
+                        else lambda _nets, *args: step_override(*args))
         self._np_dtype = np.dtype(dtype)
         self._extras_np = {k: np.asarray(v).dtype
                            for k, v in self.extras.items()}
@@ -611,8 +614,8 @@ class SlotScheduler:
         # boundary here. Timed separately — the call is device time (inline
         # execution on runtimes without async dispatch), not bookkeeping.
         d0 = time.perf_counter_ns()
-        self.state, self.meta, mask = self._flight(self.state, self.meta,
-                                                   *self._step_tail())
+        self.state, self.meta, mask = self._flight(
+            self._nets, self.state, self.meta, *self._step_tail())
         d1 = time.perf_counter_ns()
         flight = _Flight(
             tick=self.ticks,
@@ -927,8 +930,12 @@ class SlotScheduler:
         compiled executable in; returns the compile seconds. Keeps the first
         tick's timing honest — compile is no longer folded into execution."""
         t0 = time.perf_counter()
-        compiled = self._flight.lower(self.state, self.meta,
+        compiled = self._flight.lower(self._nets, self.state, self.meta,
                                       *self._step_tail()).compile()
         dt = time.perf_counter() - t0
         self._flight = compiled
         return dt
+
+    def compiled_text(self) -> str:
+        """HLO text of the AOT-compiled flight step (after `aot_compile`)."""
+        return self._flight.as_text()

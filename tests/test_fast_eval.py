@@ -289,13 +289,16 @@ def test_donated_step_bit_identical_to_undonated(gaussian_dpm):
     def run(prog):
         state = prog.init_state(slots, shape)
         state = (state[0] + x0, state[1])
+        # every slot busy from row 0 with the full budget: tick i runs row i
+        meta = np.array(prog.init_meta(slots))
+        meta[3] = 1
+        meta = jnp.asarray(meta)
         # AOT-compile exactly as the scheduler does
-        idx0 = jnp.zeros((slots,), jnp.int32)
-        compiled = prog.step.lower(state, idx0, None, None).compile()
+        compiled = prog.flight.lower(prog.nets, state, meta, None,
+                                     None).compile()
         outs = []
-        for i in range(prog.n_rows):
-            idx = jnp.full((slots,), i, jnp.int32)
-            state = compiled(state, idx, None, None)
+        for _ in range(prog.n_rows):
+            state, meta, _ = compiled(prog.nets, state, meta, None, None)
             outs.append(np.asarray(state[0]))
         return outs
 
@@ -311,8 +314,7 @@ def test_donated_step_consumes_input_state(gaussian_dpm):
     eng = _gauss_engine(gaussian_dpm)
     prog = eng.build_step(EngineSpec(solver="unipc", order=2, nfe=4))
     state = prog.init_state(2, (4,))
-    idx = jnp.zeros((2,), jnp.int32)
-    new_state = prog.step(state, idx, None, None)
+    new_state, _, _ = prog.step_flight(state, prog.init_meta(2))
     assert new_state[0].shape == state[0].shape
     with pytest.raises(RuntimeError, match="deleted"):
         _ = np.asarray(state[0]) + 1
