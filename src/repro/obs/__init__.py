@@ -1,8 +1,10 @@
 """Serving observability (DESIGN.md §15): tracing, metrics, quality probe.
 
-Layering: `trace` is stdlib-only, `metrics` adds numpy, `report` renders
-both; `probe` touches jax only inside `build_reference_fn` (building the
-reference runner), so importing the package never drags in the engine. The
+Layering: `trace` is stdlib-only until its `phase` spans import
+`jax.profiler` on first use, `metrics` adds numpy, `report` renders both;
+`probe` touches jax only inside `build_reference_fn` (building the
+reference runner), so importing the package imports no jax and never drags
+in the engine. The
 serving scheduler depends on this package — never the reverse.
 """
 
@@ -10,12 +12,12 @@ from .metrics import (METRICS_SCHEMA, MetricsRegistry, delta, parse_fullname,
                       snapshot_percentile, validate_metrics)
 from .probe import QualityProbe, build_reference_fn, probe_selected
 from .report import render_report, span_stats, write_metrics_artifact
-from .trace import TRACE_SCHEMA, Tracer, validate_trace
+from .trace import TRACE_SCHEMA, Tracer, phase, validate_trace
 
 __all__ = [
     "METRICS_SCHEMA", "MetricsRegistry", "delta", "parse_fullname",
     "snapshot_percentile", "validate_metrics",
     "QualityProbe", "build_reference_fn", "probe_selected",
     "render_report", "span_stats", "write_metrics_artifact",
-    "TRACE_SCHEMA", "Tracer", "validate_trace",
+    "TRACE_SCHEMA", "Tracer", "phase", "validate_trace",
 ]

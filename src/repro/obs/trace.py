@@ -1,20 +1,25 @@
-"""Structured event tracer -> Chrome/Perfetto ``trace_event`` JSON.
+"""Structured event tracer -> Chrome/Perfetto ``trace_event`` JSON, and the
+scheduler's phase spans on the profiler's clock.
 
-Zero-dependency (stdlib only): the tracer is a bounded ring buffer of event
-records the serving hot path appends tuples into; all formatting happens at
-export time, so an *enabled* tracer costs one `deque.append` per event plus
-whatever timestamps the caller already took (the scheduler reuses the
-`perf_counter_ns` reads it takes for host-overhead accounting — tracing adds
-no extra clock calls on the tick path). A *disabled* tracer is simply absent:
-every call site is guarded by ``if tracer is not None``, so the off path is
-bit-identical to pre-instrumentation code (pinned in `tests/test_obs.py`).
+The module imports no jax: `phase` imports `jax.profiler` on its first use.
+The tracer is a bounded ring buffer of event records the serving hot path
+appends tuples into; all formatting happens at export time, so an *enabled*
+tracer costs one `deque.append` per event. A *disabled* tracer is simply
+absent: every call site is guarded by ``if tracer is not None``, so the off
+path computes exactly what the untraced code computes (pinned in
+`tests/test_obs.py`).
 
 Event model (DESIGN.md §15):
 
-* **Tick spans** — complete ("ph": "X") events on the scheduler thread
-  track: ``tick`` encloses the per-phase children ``admission`` /
-  ``dispatch`` / ``readback`` / ``emit``. Nesting is by timestamp
-  containment, exactly how chrome://tracing renders stacks.
+* **Phase spans** — `phase(name, tracer)` opens a `jax.profiler`
+  annotation named ``serve.<name>``, which a running profiler session
+  records on its host plane, on the device trace's clock; with a tracer
+  attached the same span, under the same name and from the same
+  `perf_counter_ns` stamps, becomes a complete ("ph": "X") event.
+  ``serve.tick`` encloses ``serve.admission`` (itself enclosing one
+  ``serve.draw`` per admitted request and ``serve.admit_apply``),
+  ``serve.dispatch``, ``serve.readback`` and ``serve.emit``; nesting is by
+  timestamp containment, exactly how chrome://tracing renders stacks.
 * **Request lifecycle spans** — async events keyed by rid: "b" at submit,
   "n" instants at admit / segment boundaries, "e" at emission, carrying the
   request's tier, eval_cost, evals, and latency in the args.
@@ -30,6 +35,7 @@ obs-smoke job).
 
 from __future__ import annotations
 
+import functools
 import json
 import time
 from collections import deque
@@ -52,10 +58,10 @@ class Tracer:
     is reported in the export's `otherData.dropped_events` so a truncated
     trace is never mistaken for a complete one.
 
-    Timestamps are `time.perf_counter_ns` values; callers that already take
-    them (the scheduler's host-overhead accounting) pass them in, everything
-    else defaults to now. Export normalizes to microseconds since the
-    tracer's construction (the `ts`/`dur` unit chrome://tracing expects).
+    Timestamps are `time.perf_counter_ns` values; `phase` passes its own
+    stamps in, everything else defaults to now. Export normalizes to
+    microseconds since the tracer's construction (the `ts`/`dur` unit
+    chrome://tracing expects).
     """
 
     def __init__(self, capacity: int = 1 << 16,
@@ -162,6 +168,49 @@ class Tracer:
         with open(path, "w") as f:
             json.dump(obj, f)
         return obj
+
+
+@functools.cache
+def _profiler():
+    import jax.profiler
+
+    return jax.profiler
+
+
+class phase:
+    """Context manager for one ``serve.<name>`` span.
+
+    It opens a `jax.profiler.TraceAnnotation` (a `StepTraceAnnotation` with
+    `step_num=step` where `step` is given); `args` are its keyword
+    arguments. With no profiler session the annotation is one
+    activity check. With a `tracer`, the span is also recorded there, from
+    the stamps `t0` / `t1` (`perf_counter_ns`, taken inside the annotation),
+    which callers read for their own accounting; `args` updated inside the
+    span reach the tracer's event."""
+
+    __slots__ = ("name", "tracer", "args", "_ann", "t0", "t1")
+
+    def __init__(self, name: str, tracer: Optional[Tracer] = None,
+                 step: Optional[int] = None, **args):
+        self.name = "serve." + name
+        self.tracer = tracer
+        self.args = args
+        prof = _profiler()
+        self._ann = (prof.TraceAnnotation(self.name, **args) if step is None
+                     else prof.StepTraceAnnotation(self.name, step_num=step,
+                                                   **args))
+
+    def __enter__(self) -> "phase":
+        self._ann.__enter__()
+        self.t0 = time.perf_counter_ns()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.t1 = time.perf_counter_ns()
+        self._ann.__exit__(*exc)
+        if self.tracer is not None:
+            self.tracer.complete(self.name, self.t0, self.t1,
+                                 args=self.args or None)
 
 
 TRACE_SCHEMA = "repro.obs.trace/v1"

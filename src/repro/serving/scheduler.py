@@ -49,6 +49,7 @@ import numpy as np
 from ..engine.compiler import DONE_NONFINITE
 from ..engine.engine import StepProgram
 from ..obs.metrics import MetricsRegistry
+from ..obs.trace import phase
 from .faults import FaultInjector, FaultPlan
 from .resilience import (DEFAULT_RESILIENCE, FAIL_NONFINITE,
                          REJECT_EXPIRED, REJECT_QUEUE_FULL, Rejection,
@@ -64,6 +65,9 @@ OCCUPANCY_BUCKETS = (0.125, 0.25, 0.375, 0.5, 0.625, 0.75, 0.875, 1.0)
 LATENCY_TICK_BUCKETS = (1, 2, 4, 8, 16, 32, 64, 128, 256, 512)
 EVAL_COST_BUCKETS = (1, 2, 4, 8, 12, 16, 24, 32, 48, 64)
 HOST_PHASES = ("admission", "dispatch", "readback", "bookkeeping")
+# where the host blocks on a device->host read: the initial-latent draw,
+# the trailing readback of a flight, the desync recovery's meta read
+SYNC_SITES = ("draw", "readback", "recover")
 
 # resilience / fault-injection event counters (DESIGN.md §16). Registered
 # lazily — on the first event of each kind — so a fault-free run's metrics
@@ -301,19 +305,9 @@ class SlotScheduler:
                           if faults else None)
         self._rstate: Dict[int, dict] = {}  # rid -> retry/requeue provenance
         self._recoveries = 0
-        # host-overhead accounting (benchmarks/bench_serve.py), split by tick
-        # phase (DESIGN.md §15): admission = the _admit() call, dispatch = the
-        # step call itself (inline device execution on runtimes without async
-        # dispatch — device time, not bookkeeping), readback = time blocked
-        # on device readbacks in _consume, bookkeeping = everything else in
-        # tick(). The legacy `host_ns` (what the bench guard's host-fraction
-        # cap is defined over) is admission + bookkeeping — tick wall minus
-        # the dispatch call minus blocked readback, exactly as before.
-        self._admission_ns = 0
-        self._blocked_ns = 0
-        self._dispatch_ns = 0
-        self._bookkeeping_ns = 0
-        self._probe_ns = 0  # quality-probe replays (excluded from phases)
+        # quality-probe replays, timed apart so that they stay out of the
+        # host_phase_ns split (below)
+        self._probe_ns = 0
         # observability (DESIGN.md §15): the registry is always on — it is
         # the one accounting substrate ServeMetrics is derived from — while
         # the tracer and quality probe are opt-in (None = zero work: every
@@ -354,10 +348,25 @@ class SlotScheduler:
         self._m_cost = r.histogram(
             "request_eval_cost", EVAL_COST_BUCKETS,
             help="evals-per-latent (full-eval units) per completion")
+        # host time of tick() by phase (DESIGN.md §15.3): admission = the
+        # _admit() call, dispatch = the step call itself (inline device
+        # execution on runtimes without async dispatch), readback = time
+        # blocked in the readbacks tick() consumes, bookkeeping = the rest
+        # of tick(), so the four tile it
         self._m_phase = {p: r.counter("host_phase_ns", {"phase": p},
                                       wall=True,
                                       help="host ns per tick phase")
                          for p in HOST_PHASES}
+        # every blocking device->host read, counted where it happens (in
+        # tick(), flush() or drain() alike)
+        self._m_syncs = {s: r.counter("host_syncs", {"site": s},
+                                      help="blocking device->host reads")
+                         for s in SYNC_SITES}
+        self._m_blocked = {s: r.counter("host_blocked_ns", {"site": s},
+                                        wall=True,
+                                        help="host ns blocked in device->host "
+                                             "reads")
+                           for s in SYNC_SITES}
         # the dispatched program takes the engine's eps bundle (the weights)
         # as its first argument, so they are never compiled in as constants.
         # step_override replaces the dispatched flight step — signature
@@ -386,6 +395,10 @@ class SlotScheduler:
         queue shed it (also appended to `self.rejections`). Malformed
         requests — bad tier tag, unknown extras, guidance on an unguided
         program — still raise: those are programmer errors, not load."""
+        with phase("submit", self.tracer, rid=req.rid):
+            return self._submit(req)
+
+    def _submit(self, req: Request) -> Optional[Rejection]:
         if (req.cfg_scale is not None and float(req.cfg_scale) != 0.0
                 and not self.program.uses_cfg):
             raise ValueError(
@@ -458,35 +471,31 @@ class SlotScheduler:
         return len(self._inflight)
 
     @property
-    def host_ns(self) -> int:
-        """Accumulated host-side bookkeeping time across tick() calls,
-        excluding time spent blocked on device readbacks and the step
-        dispatch call itself (== the admission + bookkeeping phases)."""
-        return self._admission_ns + self._bookkeeping_ns
-
-    @property
-    def phase_ns(self) -> dict:
-        """Per-phase host time (DESIGN.md §15): {phase: ns} over the
-        HOST_PHASES split. admission + bookkeeping == `host_ns`."""
-        return {"admission": self._admission_ns,
-                "dispatch": self._dispatch_ns,
-                "readback": self._blocked_ns,
-                "bookkeeping": self._bookkeeping_ns}
-
-    @property
     def occupancy(self) -> float:
         """Mean fraction of slots doing useful work per tick."""
         return (self.active_slot_ticks / (self.ticks * self.slots)
                 if self.ticks else 0.0)
 
+    def _synced(self, site: str, t0_ns: int) -> None:
+        """Count one blocking device->host read at `site` that began at
+        `t0_ns` (perf_counter_ns)."""
+        self._m_syncs[site].inc()
+        self._m_blocked[site].inc(time.perf_counter_ns() - t0_ns)
+
     def _draw(self, req: Request) -> np.ndarray:
         """The request's initial latent, as host numpy (it is written into
-        the full-width admission buffer, not shipped per-request)."""
+        the full-width admission buffer, not shipped per-request). A seed's
+        latent is drawn on the device and read back: a blocking read,
+        queued behind whatever the device is running. A given x_T is host
+        data."""
         if req.x_T is not None:
             return np.asarray(req.x_T, self._np_dtype)
-        key = jax.random.PRNGKey(req.seed)
-        return np.asarray(jax.random.normal(key, self.sample_shape,
-                                            self.dtype))
+        x = jax.random.normal(jax.random.PRNGKey(req.seed), self.sample_shape,
+                              self.dtype)
+        t0 = time.perf_counter_ns()
+        x = np.asarray(x)
+        self._synced("draw", t0)
+        return x
 
     def _expired(self, req: Request, admit_now: float) -> bool:
         """Deadline check at admission time (DESIGN.md §16): a queued
@@ -555,7 +564,8 @@ class SlotScheduler:
         mask[taken] = True
         x_new = np.zeros((B,) + self.sample_shape, self._np_dtype)
         for j, r in enumerate(reqs):
-            x_new[taken[j]] = self._draw(r)
+            with phase("draw", self.tracer, rid=r.rid):
+                x_new[taken[j]] = self._draw(r)
         # on-device counters: row 0, the tier's span, busy
         meta_new = np.zeros((4, B), np.int32)
         meta_new[1, taken] = offs
@@ -570,11 +580,12 @@ class SlotScheduler:
         for k in ex_new:
             ex_new[k][taken] = [(r.extras or {}).get(k, self._extras_init[k])
                                 for r in reqs]
-        self.state, self.meta, self.g, self.extras = _apply_admission(
-            tuple(self.state), self.meta, self.g, self.extras,
-            mask, x_new, meta_new, g_new, ex_new,
-            has_cache=self.program.cache is not None,
-            uses_cfg=self.program.uses_cfg)
+        with phase("admit_apply", self.tracer):
+            self.state, self.meta, self.g, self.extras = _apply_admission(
+                tuple(self.state), self.meta, self.g, self.extras,
+                mask, x_new, meta_new, g_new, ex_new,
+                has_cache=self.program.cache is not None,
+                uses_cfg=self.program.uses_cfg)
 
     # -- the serving step ----------------------------------------------------
     def tick(self) -> List[Completion]:
@@ -582,20 +593,22 @@ class SlotScheduler:
 
         At pipeline_depth=1 the returned completions are this tick's; at
         depth N they are the completions of the tick dispatched N-1 ticks
-        ago (its readback has had N-1 device ticks to land)."""
-        t0 = time.perf_counter_ns()
-        b0 = self._blocked_ns
+        ago (its readback has had N-1 device ticks to land). The call is
+        one `serve.tick` span, numbered with the tick it steps."""
+        with phase("tick", self.tracer, step=self.ticks + 1) as tk:
+            return self._tick(tk)
+
+    def _tick(self, tk: phase) -> List[Completion]:
+        tr = self.tracer
         p0 = self._probe_ns
-        self._admit()
-        a1 = time.perf_counter_ns()
-        adm_ns = a1 - t0
-        self._admission_ns += adm_ns
+        rb0 = self._m_blocked["readback"].value
+        with phase("admission", tr) as adm:
+            self._admit()
+        adm_ns = adm.t1 - adm.t0
         busy = self._busy
         if not busy.any():
-            book_ns = time.perf_counter_ns() - a1
-            self._bookkeeping_ns += book_ns
             self._m_phase["admission"].inc(adm_ns)
-            self._m_phase["bookkeeping"].inc(book_ns)
+            self._m_phase["bookkeeping"].inc(time.perf_counter_ns() - adm.t1)
             return []
         self.ticks += 1
         self.evals += 1
@@ -613,10 +626,9 @@ class SlotScheduler:
         # (StepProgram.step_flight); nothing tick-varying crosses the host
         # boundary here. Timed separately — the call is device time (inline
         # execution on runtimes without async dispatch), not bookkeeping.
-        d0 = time.perf_counter_ns()
-        self.state, self.meta, mask = self._flight(
-            self._nets, self.state, self.meta, *self._step_tail())
-        d1 = time.perf_counter_ns()
+        with phase("dispatch", tr) as dsp:
+            self.state, self.meta, mask = self._flight(
+                self._nets, self.state, self.meta, *self._step_tail())
         flight = _Flight(
             tick=self.ticks,
             clock=(float(self.ticks) if self.clock is None else self.clock))
@@ -657,24 +669,19 @@ class SlotScheduler:
         while len(self._inflight) > self.pipeline_depth - 1:
             done.extend(self._consume(self._inflight.popleft()))
         t1 = time.perf_counter_ns()
-        book_ns = (t1 - t0 - adm_ns - (d1 - d0)
-                   - (self._blocked_ns - b0) - (self._probe_ns - p0))
-        self._dispatch_ns += d1 - d0
-        self._bookkeeping_ns += book_ns
+        dsp_ns = dsp.t1 - dsp.t0
+        rb_ns = self._m_blocked["readback"].value - rb0
+        book_ns = (t1 - tk.t0 - adm_ns - dsp_ns - rb_ns
+                   - (self._probe_ns - p0))
         self._m_phase["admission"].inc(adm_ns)
-        self._m_phase["dispatch"].inc(d1 - d0)
-        self._m_phase["readback"].inc(self._blocked_ns - b0)
+        self._m_phase["dispatch"].inc(dsp_ns)
+        self._m_phase["readback"].inc(rb_ns)
         self._m_phase["bookkeeping"].inc(book_ns)
-        if self.tracer is not None:
-            tr = self.tracer
-            tr.complete("admission", t0, a1)
-            tr.complete("dispatch", d0, d1)
-            tr.complete("tick", t0, t1,
-                        args={"tick": self.ticks, "busy": n_busy,
-                              "queue": len(self.queue),
-                              "emitted": len(done)})
+        if tr is not None:
+            tk.args.update(tick=self.ticks, busy=n_busy,
+                           queue=len(self.queue), emitted=len(done))
             tr.counter("slots", {"busy": n_busy, "queue": len(self.queue)},
-                       ts_ns=t0)
+                       ts_ns=tk.t0)
         return done
 
     def _inject(self) -> None:
@@ -721,11 +728,11 @@ class SlotScheduler:
         against the host prediction and emit the finished latents."""
         if not f.slots.size:
             return []
-        tb = time.perf_counter_ns()
-        mask_np = np.asarray(f.mask)       # blocks until the tick executed
-        lat_np = np.asarray(f.lat)         # ONE batched device_get per tick
-        te = time.perf_counter_ns()
-        self._blocked_ns += te - tb
+        with phase("readback", self.tracer):
+            t0 = time.perf_counter_ns()
+            mask_np = np.asarray(f.mask)   # blocks until the tick executed
+            lat_np = np.asarray(f.lat)     # ONE batched device_get per tick
+            self._synced("readback", t0)
         got = np.flatnonzero(mask_np)
         if not np.array_equal(got, f.slots):
             if self.resilience.recovery == "raise":
@@ -735,6 +742,24 @@ class SlotScheduler:
                     f"{f.tick} — scheduler bookkeeping desynchronized from "
                     f"the compiled step program")
             return self._recover(f, got)
+        with phase("emit", self.tracer):
+            emitted = self._emit(f, mask_np, lat_np)
+        if self.probe is not None:
+            # replay a sampled fraction against the high-NFE reference; the
+            # replay is device work, not scheduler bookkeeping — timed apart
+            # so it never pollutes the per-phase host accounting. Failed
+            # completions are never probed (their latent is non-finite).
+            pp0 = time.perf_counter_ns()
+            for req, c in emitted:
+                if c.ok and self.probe.selected(c.rid):
+                    self.probe.observe(req, c, self._draw(req))
+            self._probe_ns += time.perf_counter_ns() - pp0
+        return [c for _, c in emitted]
+
+    def _emit(self, f: _Flight, mask_np: np.ndarray,
+              lat_np: np.ndarray) -> List[Tuple[Request, Completion]]:
+        """Turn a verified flight's readback into completions (or
+        retries); returns (request, completion) pairs."""
         # on-device output validation (DESIGN.md §16): the done mask is
         # coded, and DONE_NONFINITE marks a finished slot whose latent
         # failed the finite check inside the compiled step. Those requests
@@ -797,19 +822,7 @@ class SlotScheduler:
                                 requeues=c.requeues,
                                 fail_reason=c.fail_reason)
                 self.tracer.async_end("request", c.rid, args=args)
-            self.tracer.complete("readback", tb, te)
-            self.tracer.complete("emit", te, time.perf_counter_ns())
-        if self.probe is not None:
-            # replay a sampled fraction against the high-NFE reference; the
-            # replay is device work, not scheduler bookkeeping — timed apart
-            # so it never pollutes the per-phase host accounting. Failed
-            # completions are never probed (their latent is non-finite).
-            pp0 = time.perf_counter_ns()
-            for req, c in emitted:
-                if c.ok and self.probe.selected(c.rid):
-                    self.probe.observe(req, c, self._draw(req))
-            self._probe_ns += time.perf_counter_ns() - pp0
-        return done
+        return emitted
 
     def _retry(self, req: Request, f: _Flight, prov: dict) -> None:
         """Re-admit a request whose finished latent failed validation:
@@ -841,6 +854,10 @@ class SlotScheduler:
         preserved, so a recovered request's latent still reproduces the
         clean run). Returns no completions; the requeued work re-emits
         through the normal path."""
+        with phase("recover", self.tracer, tick=f.tick):
+            return self._resync(f, got)
+
+    def _resync(self, f: _Flight, got: np.ndarray) -> List[Completion]:
         self._recoveries += 1
         if self._recoveries > self.resilience.max_recoveries:
             raise RuntimeError(
@@ -852,7 +869,9 @@ class SlotScheduler:
         affected: List[Request] = list(f.reqs)
         while self._inflight:
             affected.extend(self._inflight.popleft().reqs)
+        t0 = time.perf_counter_ns()
         meta_dev = np.asarray(self.meta)  # authoritative device counters
+        self._synced("recover", t0)
         nr = self.program.n_rows
         for s in range(self.slots):
             host_busy = bool(self._busy[s])

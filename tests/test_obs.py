@@ -241,11 +241,56 @@ def test_tracer_changes_nothing_and_trace_validates(gaussian_dpm):
     obj = json.loads(json.dumps(tr.to_json()))
     assert validate_trace(obj) == []
     stats = span_stats(obj)
-    assert {"tick", "admission", "dispatch"} <= set(stats)
-    assert stats["tick"]["count"] == m1.ticks
+    assert {"serve.tick", "serve.admission", "serve.draw",
+            "serve.admit_apply", "serve.dispatch", "serve.readback",
+            "serve.emit", "serve.submit"} <= set(stats)
+    assert stats["serve.tick"]["count"] == m1.ticks
     begins = sum(1 for e in obj["traceEvents"] if e["ph"] == "b")
     ends = sum(1 for e in obj["traceEvents"] if e["ph"] == "e")
     assert begins == ends == 9
+
+
+def test_host_syncs_count_every_blocking_read(gaussian_dpm):
+    """Every blocking device->host read is counted where it happens: one
+    draw per admitted seed request, one readback per flight that carries
+    completions, whether tick() or flush() consumes it. host_phase_ns keeps
+    tick()'s readback time only."""
+    sched = _sched(gaussian_dpm, depth=3)
+    reg = sched.registry
+
+    def val(full):
+        return reg.snapshot()[full]["value"]
+
+    for rid in range(5):
+        sched.submit(Request(rid=rid, seed=rid))
+    while sched.queue or sched.active:
+        sched.tick()
+    # 3 slots, 8 rows: rids 0-2 finish on tick 8, rids 3-4 on tick 16
+    assert val('host_syncs{site="draw"}') == val("serve_admitted") == 5
+    assert val('host_syncs{site="readback"}') == 1       # tick 8's flight
+    phase_rb = val('host_phase_ns{phase="readback"}')
+    blocked_rb = val('host_blocked_ns{site="readback"}')
+    assert phase_rb == blocked_rb > 0
+    assert len(sched.flush()) == 2
+    assert val('host_syncs{site="readback"}') == 2
+    assert val('host_blocked_ns{site="readback"}') > blocked_rb
+    assert val('host_phase_ns{phase="readback"}') == phase_rb
+    assert val('host_syncs{site="recover"}') == 0
+    assert val('host_blocked_ns{site="draw"}') > 0
+
+
+def test_obs_imports_no_jax():
+    import os
+    import subprocess
+    import sys
+    from pathlib import Path
+
+    src = str(Path(__file__).resolve().parents[1] / "src")
+    code = "import sys, repro.obs; assert 'jax' not in sys.modules"
+    p = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                       text=True, timeout=120,
+                       env={**os.environ, "PYTHONPATH": src})
+    assert p.returncode == 0, p.stderr
 
 
 def test_tiered_metrics_ride_the_registry(vp):
