@@ -16,8 +16,8 @@ Event model (DESIGN.md §15):
   records on its host plane, on the device trace's clock; with a tracer
   attached the same span, under the same name and from the same
   `perf_counter_ns` stamps, becomes a complete ("ph": "X") event.
-  ``serve.tick`` encloses ``serve.admission`` (itself enclosing one
-  ``serve.draw`` per admitted request and ``serve.admit_apply``),
+  ``serve.tick`` encloses ``serve.admission`` (itself enclosing
+  ``serve.admit_apply``),
   ``serve.dispatch``, ``serve.readback`` and ``serve.emit``; nesting is by
   timestamp containment, exactly how chrome://tracing renders stacks.
 * **Request lifecycle spans** — async events keyed by rid: "b" at submit,

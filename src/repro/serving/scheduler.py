@@ -65,8 +65,10 @@ OCCUPANCY_BUCKETS = (0.125, 0.25, 0.375, 0.5, 0.625, 0.75, 0.875, 1.0)
 LATENCY_TICK_BUCKETS = (1, 2, 4, 8, 16, 32, 64, 128, 256, 512)
 EVAL_COST_BUCKETS = (1, 2, 4, 8, 12, 16, 24, 32, 48, 64)
 HOST_PHASES = ("admission", "dispatch", "readback", "bookkeeping")
-# where the host blocks on a device->host read: the initial-latent draw,
-# the trailing readback of a flight, the desync recovery's meta read
+# where the host blocks on a device->host read: the quality probe's draw
+# of a replayed request's initial latent (admission draws on the device,
+# with no read), the trailing readback of a flight, the desync recovery's
+# meta read
 SYNC_SITES = ("draw", "readback", "recover")
 
 # resilience / fault-injection event counters (DESIGN.md §16). Registered
@@ -83,20 +85,41 @@ EVENT_COUNTER_HELP = {
 }
 
 
+def key_words(seed: int) -> np.ndarray:
+    """The raw threefry key words of `jax.random.PRNGKey(seed)`, derived
+    in numpy so that building them needs no device op: the seed's low 32
+    bits, and with x64 on its next 32 (with it off, PRNGKey truncates the
+    seed to 32 bits). `_apply_admission` draws from them on the device."""
+    seed = int(seed)
+    hi = (seed >> 32) & 0xFFFFFFFF if jax.config.jax_enable_x64 else 0
+    return np.array([hi, seed & 0xFFFFFFFF], np.uint32)
+
+
+def _normals(keys, sample_shape, dtype):
+    """One standard-normal latent per row of `keys` (uint32[n, 2] key
+    words): row i equals `jax.random.normal(PRNGKey(seed_i), ...)`."""
+    return jax.vmap(lambda k: jax.random.normal(
+        jax.random.wrap_key_data(k), sample_shape, dtype))(keys)
+
+
 @partial(jax.jit, static_argnames=("has_cache", "uses_cfg"))
 def _apply_admission(state, meta, g, extras,
-                     mask, x_new, meta_new, g_new, ex_new,
+                     mask, drawn, keys, x_new, meta_new, g_new, ex_new,
                      *, has_cache, uses_cfg):
     """Fold one tick's admissions into the device state in ONE fixed-shape
     dispatch: the host builds full-width (B-wide) masked update buffers in
-    numpy and this compiled apply selects them in. Shapes never depend on
-    how many slots admit, so the executable compiles once per (B, sample
+    numpy and this compiled apply selects them in. A seed request's x_T is
+    drawn here from its slot's key words (`drawn` marks those slots), a
+    given x_T comes in `x_new`; the draw runs for all B slots, so no shape
+    depends on the admissions. The executable compiles once per (B, sample
     shape) — eager per-count scatters would recompile for every distinct
     admission count. Module-level so the compile cache is shared across
     scheduler instances."""
     x, E = state[0], state[1]
     mx = mask.reshape(mask.shape + (1,) * (x.ndim - 1))
-    x = jnp.where(mx, x_new, x)
+    dx = drawn.reshape(mx.shape)
+    x = jnp.where(dx, _normals(keys, x.shape[1:], x.dtype),
+                  jnp.where(mx, x_new, x))
     mE = mask.reshape((1,) + mask.shape + (1,) * (E.ndim - 2))
     E = jnp.where(mE, 0.0, E)  # fresh rings -> warm-up from order 1
     if has_cache:
@@ -367,6 +390,10 @@ class SlotScheduler:
                                         help="host ns blocked in device->host "
                                              "reads")
                            for s in SYNC_SITES}
+        self._m_device_draws = r.counter(
+            "serve_device_draws",
+            help="admitted requests whose x_T the admission apply drew "
+                 "on the device from the seed")
         # the dispatched program takes the engine's eps bundle (the weights)
         # as its first argument, so they are never compiled in as constants.
         # step_override replaces the dispatched flight step — signature
@@ -377,6 +404,7 @@ class SlotScheduler:
         self._flight = (program.flight if step_override is None
                         else lambda _nets, *args: step_override(*args))
         self._np_dtype = np.dtype(dtype)
+        self._x_zero = jnp.zeros((slots,) + self.sample_shape, dtype)
         self._extras_np = {k: np.asarray(v).dtype
                            for k, v in self.extras.items()}
 
@@ -483,15 +511,16 @@ class SlotScheduler:
         self._m_blocked[site].inc(time.perf_counter_ns() - t0_ns)
 
     def _draw(self, req: Request) -> np.ndarray:
-        """The request's initial latent, as host numpy (it is written into
-        the full-width admission buffer, not shipped per-request). A seed's
-        latent is drawn on the device and read back: a blocking read,
-        queued behind whatever the device is running. A given x_T is host
-        data."""
+        """The request's initial latent as host numpy, for the quality
+        probe's replay (serving draws it inside the admission apply). A
+        seed's latent is drawn on the device from the same key words
+        `_apply_admission` uses and read back: a counted blocking read. A
+        given x_T is host data."""
         if req.x_T is not None:
             return np.asarray(req.x_T, self._np_dtype)
-        x = jax.random.normal(jax.random.PRNGKey(req.seed), self.sample_shape,
-                              self.dtype)
+        x = jax.random.normal(
+            jax.random.wrap_key_data(jnp.asarray(key_words(req.seed))),
+            self.sample_shape, self.dtype)
         t0 = time.perf_counter_ns()
         x = np.asarray(x)
         self._synced("draw", t0)
@@ -557,15 +586,29 @@ class SlotScheduler:
                           "offset": int(offs[j]), "budget": int(budgets[j]),
                           "tier": r.tier})
         # full-width masked update buffers, built host-side in numpy; the
-        # jitted apply folds latents + meta counters + guidance + extras into
-        # the device state in ONE fixed-shape dispatch per tick
+        # jitted apply draws the seed requests' latents and folds latents +
+        # meta counters + guidance + extras into the device state in ONE
+        # fixed-shape dispatch per tick. A seed crosses from the host as its
+        # key words, and only a request's own x_T as a latent: a tick with
+        # none reuses a resident zero buffer, not a fresh full-width upload
         B = self.slots
         mask = np.zeros(B, bool)
         mask[taken] = True
-        x_new = np.zeros((B,) + self.sample_shape, self._np_dtype)
-        for j, r in enumerate(reqs):
-            with phase("draw", self.tracer, rid=r.rid):
-                x_new[taken[j]] = self._draw(r)
+        keys = np.zeros((B, 2), np.uint32)
+        drawn = np.zeros(B, bool)
+        x_new = None
+        for s, r in zip(taken, reqs):
+            if r.x_T is None:
+                keys[s] = key_words(r.seed)
+                drawn[s] = True
+            else:
+                if x_new is None:
+                    x_new = np.zeros((B,) + self.sample_shape,
+                                     self._np_dtype)
+                x_new[s] = np.asarray(r.x_T, self._np_dtype)
+        # placed like the resident buffer, so both hit one executable
+        x_new = self._x_zero if x_new is None else jax.device_put(x_new)
+        self._m_device_draws.inc(int(drawn.sum()))
         # on-device counters: row 0, the tier's span, busy
         meta_new = np.zeros((4, B), np.int32)
         meta_new[1, taken] = offs
@@ -583,7 +626,7 @@ class SlotScheduler:
         with phase("admit_apply", self.tracer):
             self.state, self.meta, self.g, self.extras = _apply_admission(
                 tuple(self.state), self.meta, self.g, self.extras,
-                mask, x_new, meta_new, g_new, ex_new,
+                mask, drawn, keys, x_new, meta_new, g_new, ex_new,
                 has_cache=self.program.cache is not None,
                 uses_cfg=self.program.uses_cfg)
 

@@ -241,9 +241,10 @@ def test_tracer_changes_nothing_and_trace_validates(gaussian_dpm):
     obj = json.loads(json.dumps(tr.to_json()))
     assert validate_trace(obj) == []
     stats = span_stats(obj)
-    assert {"serve.tick", "serve.admission", "serve.draw",
-            "serve.admit_apply", "serve.dispatch", "serve.readback",
-            "serve.emit", "serve.submit"} <= set(stats)
+    assert {"serve.tick", "serve.admission", "serve.admit_apply",
+            "serve.dispatch", "serve.readback", "serve.emit",
+            "serve.submit"} <= set(stats)
+    assert "serve.draw" not in stats    # admission draws on the device
     assert stats["serve.tick"]["count"] == m1.ticks
     begins = sum(1 for e in obj["traceEvents"] if e["ph"] == "b")
     ends = sum(1 for e in obj["traceEvents"] if e["ph"] == "e")
@@ -252,9 +253,9 @@ def test_tracer_changes_nothing_and_trace_validates(gaussian_dpm):
 
 def test_host_syncs_count_every_blocking_read(gaussian_dpm):
     """Every blocking device->host read is counted where it happens: one
-    draw per admitted seed request, one readback per flight that carries
-    completions, whether tick() or flush() consumes it. host_phase_ns keeps
-    tick()'s readback time only."""
+    readback per flight that carries completions, whether tick() or flush()
+    consumes it; admission draws seed requests' latents on the device and
+    reads none back. host_phase_ns keeps tick()'s readback time only."""
     sched = _sched(gaussian_dpm, depth=3)
     reg = sched.registry
 
@@ -266,7 +267,8 @@ def test_host_syncs_count_every_blocking_read(gaussian_dpm):
     while sched.queue or sched.active:
         sched.tick()
     # 3 slots, 8 rows: rids 0-2 finish on tick 8, rids 3-4 on tick 16
-    assert val('host_syncs{site="draw"}') == val("serve_admitted") == 5
+    assert val("serve_device_draws") == val("serve_admitted") == 5
+    assert val('host_syncs{site="draw"}') == 0
     assert val('host_syncs{site="readback"}') == 1       # tick 8's flight
     phase_rb = val('host_phase_ns{phase="readback"}')
     blocked_rb = val('host_blocked_ns{site="readback"}')
@@ -276,7 +278,7 @@ def test_host_syncs_count_every_blocking_read(gaussian_dpm):
     assert val('host_blocked_ns{site="readback"}') > blocked_rb
     assert val('host_phase_ns{phase="readback"}') == phase_rb
     assert val('host_syncs{site="recover"}') == 0
-    assert val('host_blocked_ns{site="draw"}') > 0
+    assert val('host_blocked_ns{site="draw"}') == 0
 
 
 def test_obs_imports_no_jax():
