@@ -2,7 +2,8 @@
 configuration's plain float32 reference, run over the same requests.
 
 For each sampled request the reference draws the starting latent from the
-request's seed, takes its class id and guidance scale, and samples the whole
+request's seed, takes its conditioning as the configuration's kind makes it
+(bench/traffic/cond_<kind>.py) and its guidance scale, and samples the whole
 trajectory. The number compared is the worst request's relative error,
 ||x_served - x_ref|| / ||x_ref||. The reference makes its weights again from
 the run's seed; it takes nothing the program has made.
@@ -15,6 +16,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from bench import weights
+from bench.traffic import generate
 
 NAME = "latent_rel_err_max"
 # the control's precision: float8 e4m3's 3 explicit mantissa bits, the step
@@ -34,6 +36,7 @@ def reference_latents(config: dict, seed: int, specs: list,
     size (the last block padded with its first request). `bits` gives the
     control: the reference computed at that many mantissa bits."""
     ref = weights.reference(config)
+    cond = generate.conditioning(config)
     params = weights.make_params(config, seed)
     m, srv = config["model"], config["serving"]
     shape = (m["patch_tokens"], m["latent_dim"])
@@ -44,11 +47,9 @@ def reference_latents(config: dict, seed: int, specs: list,
         n = len(part)
         part += [part[0]] * (block - n)
         x_T = start_latents([s.seed for s in part], shape)
-        ids = [srv["null_class"] if s.class_id is None else s.class_id
-               for s in part]
         g = [s.cfg_scale for s in part] if srv["guided"] else None
         x0 = ref.sample(params, m, config["schedule"], config["solver"], x_T,
-                        ids, g=g, null_class=srv["null_class"], bits=bits)
+                        g=g, bits=bits, **cond.reference_inputs(config, part))
         out.append(np.asarray(x0)[:n])
     del params
     return np.concatenate(out) if out else np.zeros((0,) + shape, np.float32)
@@ -80,8 +81,6 @@ def control_error(config: dict, seed: int, bits: int = CONTROL_BITS) -> float:
     """The control's compared number: the reference computed at `bits`
     mantissa bits in the program's place, over as many requests as a
     window's check takes, against the float32 reference."""
-    from bench.traffic import generate
-
     n = int(config["check"]["per_slot"]) * int(config["serving"]["slots"])
     specs = generate.Requests(config, seed).take(n)
     return worst_error(reference_latents(config, seed, specs, bits=bits),

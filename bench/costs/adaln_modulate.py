@@ -17,10 +17,12 @@ def kind(op_name: str):
     return None
 
 
-def per_call(kind: str, config: dict, rows_per_slot: int):
+def per_call(kind: str, config: dict, rows_per_slot: int) -> list:
+    """[(flops, bytes)] of one call: each of a block's calls of a kind
+    sees the same shapes."""
     s = step_shapes(config, rows_per_slot)
     B, T, D, eb = s["B"], s["T"], s["D"], s["eb"]
     if kind == "modulate":
         # mean, variance, normalise, scale, shift: ~8 per element
-        return 8 * B * T * D, (2 * B * T * D + 2 * B * D) * eb
-    return 2 * B * T * D, (3 * B * T * D + B * D) * eb
+        return [(8 * B * T * D, (2 * B * T * D + 2 * B * D) * eb)]
+    return [(2 * B * T * D, (3 * B * T * D + B * D) * eb)]

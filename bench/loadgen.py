@@ -60,6 +60,7 @@ class LoadGen:
                  per_slot: int, annotate: bool = False):
         self.sched = served.sched
         self.traffic = traffic
+        self.config = config
         self.requests = generate.Requests(config, seed)
         self.pending = {}                          # rid -> Spec, in flight
         self.rng = np.random.default_rng(np.random.SeedSequence([seed, 3]))
@@ -82,7 +83,7 @@ class LoadGen:
     def _submit(self, spec, due: float) -> None:
         self.pending[spec.rid] = spec
         with self.span("bench.submit"):
-            rej = self.sched.submit(system.request(spec))
+            rej = self.sched.submit(system.request(self.config, spec))
         if rej is not None:
             raise RuntimeError(f"request {spec.rid} refused: {rej}")
         self._due[spec.rid] = due

@@ -23,18 +23,27 @@ def counter(win, name: str) -> float:
 def roofline(rec, kernel: str):
     """Share (%) of the least time the kernel's calls could take (the larger
     of operations over peak and bytes over HBM bandwidth, counted from the
-    algorithm's shapes) in the device time its calls took."""
+    algorithm's shapes for each call) in the device time its calls took.
+
+    Each traced op is charged the call of `per_call`'s list that it makes:
+    the only one, or the one the cost module's `call_of` tells from the op's
+    operands. Where an op's call is not known there is no value."""
     from bench.cell import cost_module
 
     if rec.trace is None or rec.peaks is None:
         return None
     cost = cost_module(kernel)
+    ops = {name: (cost.kind(name), op) for name, op in rec.trace["ops"].items()}
     least = took = 0.0
-    for name, op in rec.trace["ops"].items():
-        kind = cost.kind(name)
+    for name, (kind, op) in ops.items():
         if kind is None:
             continue
-        flops, nbytes = cost.per_call(kind, rec.config, rec.rows_per_slot)
+        each = cost.per_call(kind, rec.config, rec.rows_per_slot)
+        i = 0 if len(each) == 1 else cost.call_of(
+            name, [n for n, (k, _) in ops.items() if k == kind], rec.config)
+        if i is None:
+            return None
+        flops, nbytes = each[i]
         least += op["calls"] * max(flops / rec.peaks["bf16_flops"],
                                    nbytes / rec.peaks["hbm_bytes_per_s"])
         took += op["seconds"]

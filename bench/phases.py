@@ -2,8 +2,9 @@
 
 The scheduler opens a `jax.profiler` annotation named `serve.<phase>`
 around each of its phases (`repro.obs.trace.phase`): `serve.tick`, and
-inside it `serve.admission` (holding one `serve.draw` per admitted request
-and `serve.admit_apply`), `serve.dispatch`, `serve.readback`, `serve.emit`;
+inside it `serve.admission` (holding `serve.admit_apply`, which also draws
+the admitted latents on the device), `serve.dispatch`, `serve.readback`,
+`serve.emit`;
 besides, `serve.submit` and `serve.recover`. They lie on the host plane of
 the `.xplane.pb`, on the device trace's clock, beside the benchmark's own
 `bench.*` spans, which `bench/trace.py` reads. Here the same reduction,

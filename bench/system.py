@@ -13,6 +13,8 @@ from dataclasses import dataclass
 
 import jax
 
+from bench.traffic.generate import conditioning
+
 
 @dataclass
 class Served:
@@ -77,20 +79,20 @@ def build(config: dict, params) -> Served:
         cfg_scale=float(srv["cfg_scales"][0]) if guided else 0.0)
     program = engine.build_step(spec)
     sched = SlotScheduler(program, slots, (cfg.patch_tokens, cfg.latent_dim),
-                          extras_init={"class_ids": srv["null_class"]},
+                          extras_init=conditioning(config).extras_init(config),
                           pipeline_depth=int(srv["pipeline_depth"]))
     compile_s = sched.aot_compile()
     return Served(sched=sched, rows_per_slot=2 if guided else 1,
                   compile_s=compile_s)
 
 
-def request(spec):
-    """The program's Request for one traffic `Spec`."""
+def request(config: dict, spec):
+    """The program's Request for one traffic `Spec`, its conditioning made
+    by the configuration's kind."""
     from repro.serving import Request
 
     return Request(rid=spec.rid, seed=spec.seed, cfg_scale=spec.cfg_scale,
-                   extras=(None if spec.class_id is None
-                           else {"class_ids": spec.class_id}))
+                   extras=conditioning(config).extras(config, spec))
 
 
 def counters(sched) -> dict:

@@ -15,8 +15,22 @@ module states `OPEN`:
   mix. The run's seed only permutes them, so every seed gets the same work
   and the same bursts, in another order.
 
-Per-request inputs (the request's latent seed, class id, guidance scale)
-come from the run's seed.
+Per-request inputs (the request's latent seed, its conditioning, its
+guidance scale) come from the run's seed. The conditioning is the
+configuration's: its file names a kind, `"conditioning": {"kind": ...}`,
+and each kind is a module of its own, `bench/traffic/cond_<kind>.py`, found
+by name. A kind gives
+
+- `draw(config, rng)`: the hashable scalar a request's `Spec` carries, drawn
+  from the run's stream right after the request's latent seed;
+- `extras(config, spec)` and `extras_init(config)`: the program's per-request
+  conditioning and its value in a slot that holds no request;
+- `reference_inputs(config, specs)`: the keyword arguments, stacked over the
+  requests, that the configuration's reference `sample` takes.
+
+Arrays a kind needs (a caption's embedding) are made from the request's own
+seed where they are used, so the program and the reference each make them
+and neither takes them from the other.
 """
 
 from __future__ import annotations
@@ -24,7 +38,7 @@ from __future__ import annotations
 import importlib
 import re
 from dataclasses import dataclass
-from typing import Optional
+from typing import Hashable, Optional
 
 import numpy as np
 
@@ -36,7 +50,7 @@ class Spec:
     """One request as the traffic sends it."""
     rid: int
     seed: int
-    class_id: Optional[int]
+    cond: Hashable          # what the conditioning kind drew for it
     cfg_scale: Optional[float]
 
 
@@ -47,8 +61,8 @@ class Requests:
     def __init__(self, config: dict, seed: int):
         serving = config["serving"]
         self.scales = list(serving["cfg_scales"]) if serving["guided"] else []
-        self.classes = (config["model"]["num_classes"]
-                        if serving["class_conditional"] else 0)
+        self.config = config
+        self.cond = conditioning(config)
         self.rng = np.random.default_rng(np.random.SeedSequence([seed, 1]))
         self.next_rid = 0
 
@@ -58,22 +72,33 @@ class Requests:
             rid = self.next_rid
             self.next_rid += 1
             s = int(self.rng.integers(0, 2 ** 31 - 1))
-            c = int(self.rng.integers(0, self.classes)) if self.classes else None
+            c = self.cond.draw(self.config, self.rng)
             g = self.scales[rid % len(self.scales)] if self.scales else None
             out.append(Spec(rid, s, c, g))
         return out
 
 
+def _module(prefix: str, what: str, k):
+    """bench/traffic/<prefix>_<k>.py, the module of `what` kind `k`."""
+    if not isinstance(k, str) or not _KIND.match(k):
+        raise ValueError(f"{what} kind must be a lower-case name, got {k!r}")
+    try:
+        return importlib.import_module(f"bench.traffic.{prefix}_{k}")
+    except ModuleNotFoundError as e:
+        raise ValueError(f"no module bench/traffic/{prefix}_{k}.py for "
+                         f"{what} kind {k!r}") from e
+
+
+def conditioning(config: dict):
+    """The module of the configuration's conditioning kind,
+    bench/traffic/cond_<kind>.py."""
+    return _module("cond", "conditioning",
+                   config.get("conditioning", {}).get("kind"))
+
+
 def kind(traffic: dict):
     """The module of the mix's kind, bench/traffic/gen_<kind>.py."""
-    k = traffic.get("kind")
-    if not isinstance(k, str) or not _KIND.match(k):
-        raise ValueError(f"traffic kind must be a lower-case name, got {k!r}")
-    try:
-        return importlib.import_module(f"bench.traffic.gen_{k}")
-    except ModuleNotFoundError as e:
-        raise ValueError(f"no generator bench/traffic/gen_{k}.py for "
-                         f"traffic kind {k!r}") from e
+    return _module("gen", "traffic", traffic.get("kind"))
 
 
 def is_open(traffic: dict) -> bool:

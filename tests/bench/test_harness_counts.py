@@ -43,11 +43,13 @@ def test_kernel_counts_use_unpadded_shapes(name, rows):
     m, slots = c["model"], c["serving"]["slots"]
     B, T, D = slots * rows, m["patch_tokens"], m["d_model"]
     H, hd = m["num_heads"], m["head_dim"]
-    flops, nbytes = flash_attention.per_call("attention", c, rows)
+    # one self call a block, over the patch tokens
+    assert c["attention"] == [{"q": "patch_tokens", "kv": "patch_tokens"}]
+    [(flops, nbytes)] = flash_attention.per_call("attention", c, rows)
     assert flops == 4 * B * H * T * T * hd
     assert nbytes == 4 * B * H * T * hd * 2          # bf16 q, k, v, out
-    _, mod_bytes = adaln_modulate.per_call("modulate", c, rows)
-    _, gate_bytes = adaln_modulate.per_call("gate_residual", c, rows)
+    [(_, mod_bytes)] = adaln_modulate.per_call("modulate", c, rows)
+    [(_, gate_bytes)] = adaln_modulate.per_call("gate_residual", c, rows)
     assert mod_bytes == (2 * B * T * D + 2 * B * D) * 2
     assert gate_bytes == (3 * B * T * D + B * D) * 2
 

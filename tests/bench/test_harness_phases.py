@@ -55,25 +55,30 @@ def test_program_spans_nest_inside_the_benchmarks(traced):
     by = {}
     for ev in program:
         by.setdefault(ev[0], []).append(ev)
-    assert {"serve.tick", "serve.admission", "serve.draw",
-            "serve.admit_apply", "serve.dispatch", "serve.readback",
-            "serve.emit", "serve.submit"} <= set(by)
+    assert {"serve.tick", "serve.admission", "serve.admit_apply",
+            "serve.dispatch", "serve.readback", "serve.emit",
+            "serve.submit"} <= set(by)
+    # admission draws every latent inside its one apply: no span a request
+    assert "serve.draw" not in by
     bench_ticks = [ev for ev in raw["host"] if ev[0] == "bench.tick"]
     ticks = by["serve.tick"]
     assert all(_inside(ev, bench_ticks) for ev in ticks)
     assert all(_inside(ev, ticks) for ev in by["serve.admission"])
-    assert all(_inside(ev, by["serve.admission"]) for ev in by["serve.draw"])
     assert all(_inside(ev, by["serve.admission"])
                for ev in by["serve.admit_apply"])
     for name in ("serve.dispatch", "serve.readback", "serve.emit"):
         assert all(_inside(ev, ticks) for ev in by[name]), name
     submits = [ev for ev in raw["host"] if ev[0] == "bench.submit"]
     assert all(_inside(ev, submits) for ev in by["serve.submit"])
-    # one serve.tick per tick of the window, one serve.draw per admission
+    # one serve.tick per tick of the window, one serve.admit_apply per
+    # tick that admits, each admitted latent drawn on the device
     assert len(ticks) == win.ticks
     admitted = (win.counters1["serve_admitted"]
                 - win.counters0["serve_admitted"])
-    assert len(by["serve.draw"]) == admitted > 0
+    draws = (win.counters1["serve_device_draws"]
+             - win.counters0["serve_device_draws"])
+    assert draws == admitted > 0
+    assert 0 < len(by["serve.admit_apply"]) <= admitted
 
 
 def test_tracer_export_carries_the_profilers_spans(traced):
@@ -89,14 +94,17 @@ def test_tracer_export_carries_the_profilers_spans(traced):
 
 
 def test_draws_are_the_counted_syncs_at_admission(traced):
+    """Admission draws each latent on the device and reads nothing back:
+    the draws are counted, and no sync or wait is counted at the draw."""
     win, _, _, _ = traced
 
     def d(k):
         return win.counters1[k] - win.counters0[k]
 
-    assert d('host_syncs{site="draw"}') == d("serve_admitted")
+    assert d("serve_device_draws") == d("serve_admitted") > 0
+    assert d('host_syncs{site="draw"}') == 0
+    assert d('host_blocked_ns{site="draw"}') == 0
     assert d('host_syncs{site="readback"}') > 0
-    assert d('host_blocked_ns{site="draw"}') > 0
 
 
 def test_idle_by_phase_on_the_traced_run(traced):
@@ -108,12 +116,12 @@ def test_idle_by_phase_on_the_traced_run(traced):
     idle = phases.idle_by_phase(idle_dev, program)
     assert set(idle) <= {n for n, _, _ in program} | {"other"}
     assert sum(idle.values()) == pytest.approx(window_s)
-    assert idle["serve.draw"] > 0 and idle["serve.dispatch"] > 0
+    assert "serve.draw" not in idle
+    assert idle["serve.admit_apply"] > 0 and idle["serve.dispatch"] > 0
     assert idle["other"] < idle["serve.tick"] + idle["serve.dispatch"]
     share = phases.admission_idle_share(idle_dev, program)
-    assert 100 * (idle["serve.admission"] + idle["serve.draw"]
-                  + idle["serve.admit_apply"]) / window_s \
-        == pytest.approx(share)
+    assert 100 * (idle["serve.admission"] + idle["serve.admit_apply"]) \
+        / window_s == pytest.approx(share)
     assert phases.idle_by_phase(raw, program) == {}
 
 

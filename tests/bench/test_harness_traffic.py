@@ -81,15 +81,19 @@ def test_requests_are_deterministic_per_seed(name):
         assert {r.cfg_scale for r in a} == set(cfg["serving"]["cfg_scales"])
     else:
         assert all(r.cfg_scale is None for r in a)
+    assert cfg["conditioning"] == {"kind": "class"}
     if cfg["serving"]["class_conditional"]:
-        assert all(0 <= r.class_id < cfg["model"]["num_classes"] for r in a)
+        assert all(0 <= r.cond < cfg["model"]["num_classes"] for r in a)
     else:
-        assert all(r.class_id is None for r in a)
+        assert all(r.cond is None for r in a)
 
 
 def test_unknown_kind_is_refused():
     with pytest.raises(ValueError):
         generate.is_open({"kind": "zipf"})
+    for cond in ({"kind": "pixels"}, {"kind": "Class"}, {}):
+        with pytest.raises(ValueError):
+            generate.conditioning({"conditioning": cond})
 
 
 @pytest.mark.parametrize("name,open_", [("backlog", False), ("poisson", True),
@@ -99,3 +103,11 @@ def test_each_kind_is_a_module_found_by_name(name, open_):
     assert mod.__name__ == f"bench.traffic.gen_{name}"
     assert (ROOT / "bench" / "traffic" / f"gen_{name}.py").is_file()
     assert generate.is_open({"kind": name}) is open_
+
+
+@pytest.mark.parametrize("name", ["class", "text"])
+def test_each_conditioning_kind_is_a_module_found_by_name(name):
+    mod = generate.conditioning({"conditioning": {"kind": name}})
+    assert mod.__name__ == f"bench.traffic.cond_{name}"
+    for f in ("draw", "extras", "extras_init", "reference_inputs"):
+        assert callable(getattr(mod, f))
