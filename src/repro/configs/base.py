@@ -59,6 +59,12 @@ class ModelConfig:
     # diffusion
     latent_dim: int = 0             # diffusion-LM latent width (0 = AR only)
     patch_tokens: int = 0           # DiT tokens per image
+    # text-conditioned DiT (PixArt-alpha): the caption a request carries,
+    # a text encoder's output of caption_tokens rows of caption_dim (0 = a
+    # class-conditioned DiT)
+    caption_tokens: int = 0
+    caption_dim: int = 0
+    norm_eps: float = 1e-5          # the DiT blocks' LayerNorm eps
 
     # numerics
     dtype: str = "bfloat16"
@@ -136,6 +142,9 @@ class ModelConfig:
             base.update(encoder_layers=2, audio_frames=min(self.audio_frames, 32) or 32)
         if self.latent_dim:
             base.update(latent_dim=min(self.latent_dim, 32))
+        if self.caption_dim:
+            base.update(caption_tokens=min(self.caption_tokens, 16),
+                        caption_dim=min(self.caption_dim, 64))
         base.update(overrides)
         return dataclasses.replace(self, **base)
 

@@ -12,7 +12,7 @@ ARCH_IDS = [
     "qwen2.5-3b", "granite-moe-3b-a800m", "llama-3.2-vision-90b",
     "deepseek-67b", "mamba2-780m",
     # paper-native diffusion backbones (beyond the assigned pool)
-    "dit-i256", "dit-cifar",
+    "dit-i256", "dit-cifar", "pixart-xl2-512",
 ]
 
 _MODULES = {a: a.replace("-", "_").replace(".", "_") for a in ARCH_IDS}

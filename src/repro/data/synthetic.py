@@ -8,6 +8,8 @@ Deterministic, seekable streams so training is reproducible and resumable:
 * `latent_images` — smooth random-field latents for DiT training.
 * `stub_embeds` — the modality-frontend stand-ins (audio frames / image
   patches) required by the [audio]/[vlm] carve-out.
+* `caption` — a text-encoder output stand-in for a text-conditioned DiT:
+  a caption of a seeded length, zeros past it.
 """
 
 from __future__ import annotations
@@ -69,3 +71,15 @@ def stub_embeds(batch: int, tokens: int, d_model: int, seed: int = 0):
 def class_ids(batch: int, num_classes: int = 1000, seed: int = 0):
     return np.random.default_rng(seed).integers(
         0, num_classes, size=(batch,)).astype(np.int32)
+
+
+def caption(seed: int, tokens: int, dim: int):
+    """(embedding float32 [tokens, dim], length) of one request's caption,
+    drawn from its seed: a length uniform on 1..tokens, standard normal
+    rows up to it and zeros past it (a padded text-encoder output)."""
+    rng = np.random.default_rng(np.random.SeedSequence([int(seed), 7]))
+    length = int(rng.integers(1, tokens + 1))
+    out = np.zeros((tokens, dim), np.float32)
+    out[:length] = rng.standard_normal((length, dim), dtype=np.float32)
+    return out, length
+

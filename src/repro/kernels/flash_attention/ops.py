@@ -16,7 +16,9 @@ Three backends, the same explicit policy as ``kernels/unipc_update/ops.py``
 a backend explicitly (tests, CI, the `cfg.attention_backend` model knob) or
 let the dispatcher choose by platform. Sequence lengths are padded up to
 block multiples for the kernel backends: key padding is masked inside the
-kernel via kv_len, query padding is sliced off.
+kernel via kv_len, query padding is sliced off. `kv_lens` gives each batch
+row a key length of its own (a caption shorter than its buffer); without
+it the kernel is called exactly as before.
 """
 
 from __future__ import annotations
@@ -29,9 +31,13 @@ from ..dispatch import (BACKENDS, resolve_backend,  # noqa: F401 (re-export)
 from .kernel import DEFAULT_BLOCK_K, DEFAULT_BLOCK_Q, flash_attention
 
 
-def attention(q, k, v, *, causal=True, window=None, backend=None,
-              force_pallas=False, blk_q=DEFAULT_BLOCK_Q, blk_k=DEFAULT_BLOCK_K):
+def attention(q, k, v, *, causal=True, window=None, kv_lens=None,
+              backend=None, force_pallas=False, blk_q=DEFAULT_BLOCK_Q,
+              blk_k=DEFAULT_BLOCK_K):
     """(B, Hq, Sq, D) x (B, Hkv, Skv, D) -> (B, Hq, Sq, D).
+
+    `kv_lens`: optional int (B,), 1 <= kv_lens[b] <= Skv; row b attends to
+    its first kv_lens[b] keys only.
 
     `backend` pins one of BACKENDS; `force_pallas` (kept for tests and
     benchmarks) means "run the kernel even off-TPU", i.e. compiled on TPU,
@@ -40,7 +46,8 @@ def attention(q, k, v, *, causal=True, window=None, backend=None,
     """
     backend = resolve_backend(backend, force_pallas, select_backend)
     if backend == "jnp":
-        return ref.attention(q, k, v, causal=causal, window=window)
+        return ref.attention(q, k, v, causal=causal, window=window,
+                             kv_lens=kv_lens)
     B, Hq, Sq, D = q.shape
     Skv = k.shape[2]
     pq = (-Sq) % blk_q
@@ -50,7 +57,7 @@ def attention(q, k, v, *, causal=True, window=None, backend=None,
     if pk:
         k = jnp.pad(k, ((0, 0), (0, 0), (0, pk), (0, 0)))
         v = jnp.pad(v, ((0, 0), (0, 0), (0, pk), (0, 0)))
-    out = flash_attention(q, k, v, causal=causal, window=window,
+    out = flash_attention(q, k, v, kv_lens, causal=causal, window=window,
                           blk_q=blk_q, blk_k=blk_k,
                           interpret=backend == "interpret", kv_len=Skv)
     return out[:, :, :Sq]

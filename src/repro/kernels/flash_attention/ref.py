@@ -6,8 +6,9 @@ import jax
 import jax.numpy as jnp
 
 
-def attention(q, k, v, *, causal=True, window=None):
-    """q: (B, Hq, Sq, D); k, v: (B, Hkv, Skv, D). fp32 softmax."""
+def attention(q, k, v, *, causal=True, window=None, kv_lens=None):
+    """q: (B, Hq, Sq, D); k, v: (B, Hkv, Skv, D). fp32 softmax. `kv_lens`
+    (B,) masks each batch row's keys past its own length."""
     B, Hq, Sq, D = q.shape
     Hkv, Skv = k.shape[1], k.shape[2]
     group = Hq // Hkv
@@ -21,7 +22,10 @@ def attention(q, k, v, *, causal=True, window=None):
         ok &= kpos <= qpos
     if window is not None:
         ok &= kpos > qpos - window
-    s = jnp.where(ok[None, None, None], s, -1e30)
+    ok = ok[None, None, None]
+    if kv_lens is not None:
+        ok = ok & (kpos < jnp.asarray(kv_lens)[:, None, None, None, None])
+    s = jnp.where(ok, s, -1e30)
     w = jax.nn.softmax(s, axis=-1)
     o = jnp.einsum("bhgqk,bhkd->bhgqd", w, v.astype(jnp.float32))
     return o.reshape(B, Hq, Sq, D).astype(q.dtype)

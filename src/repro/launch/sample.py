@@ -49,6 +49,13 @@ def build_engine(cfg, params, schedule, batch: int, seed: int = 0,
     order), the eps branches take `class_ids` as a per-call (B,) keyword
     argument, which the serving scheduler scatters per request.
 
+    A text-conditioned config (`cfg.caption_dim > 0`, PixArt-alpha) takes
+    `caption` (B, caption_tokens, caption_dim) and `caption_len` (B,) in
+    place of class ids, per request only. The guidance half is the learned
+    null caption `y_embedding` under each row's own caption length, as
+    PixArt's sampling scripts pair them. The cache and quantized tiers do
+    not serve it.
+
     eval_dtype="bfloat16" is the fast serving eval (DESIGN.md §11): the
     network's params-at-use and activations run in bf16 (params are pre-cast
     once, so serving HBM reads are halved; the conditioning MLP keeps its
@@ -76,6 +83,10 @@ def build_engine(cfg, params, schedule, batch: int, seed: int = 0,
     if eval_dtype not in ("float32", "bfloat16"):
         raise ValueError(f"eval_dtype must be 'float32' or 'bfloat16', "
                          f"got {eval_dtype!r}")
+    if cfg.caption_dim and (cache_block or quant != "none"):
+        raise ValueError(f"{cfg.arch_id!r} is text-conditioned; the cached "
+                         f"and quantized tiers serve class-conditioned "
+                         f"DiTs only")
     if quant != "none" and cfg.family != "dit":
         raise ValueError(f"the quantized denoiser path needs the dit "
                          f"family; {cfg.arch_id!r} is family "
@@ -135,6 +146,9 @@ def build_engine(cfg, params, schedule, batch: int, seed: int = 0,
                              "(class-conditional eps-net)")
         return SamplerEngine(schedule, eps=eps_with({}),
                              eval_dtype=eval_dtype, quant=quant)
+    if cfg.caption_dim:
+        return _text_engine(cfg, net, params, schedule, per_request_cond,
+                            eval_dtype)
     null = jnp.full((batch,), NULL_CLASS_ID, jnp.int32)
     if per_request_cond:
         def eps_cond(p, x, t, class_ids):
@@ -163,6 +177,33 @@ def build_engine(cfg, params, schedule, batch: int, seed: int = 0,
         eval_dtype=eval_dtype, quant=quant,
         **cache_kw(baked={"class_ids": ids}),
     )
+
+
+def _text_engine(cfg, net, params, schedule, per_request_cond,
+                 eval_dtype) -> SamplerEngine:
+    """The eps branches of a text-conditioned DiT, each taking a caption
+    per request. The stacked (guided) branch puts the learned null caption
+    `y_embedding` under the second half's rows, each with its own row's
+    caption length."""
+    if not per_request_cond:
+        raise ValueError(f"{cfg.arch_id!r} is text-conditioned: its "
+                         f"engine takes a caption per request "
+                         f"(per_request_cond=True)")
+
+    def eps_cond(p, x, t, caption, caption_len):
+        return net(p, x, jnp.asarray(t, jnp.float32),
+                   {"caption": caption, "caption_len": caption_len})
+
+    def eps_stacked(p, xx, t, caption, caption_len):
+        null = jnp.broadcast_to(p["backbone"]["y_embedding"].astype(
+            caption.dtype), caption.shape)
+        lens = jnp.asarray(caption_len, jnp.int32)
+        return eps_cond(p, xx, t, jnp.concatenate([caption, null]),
+                        jnp.concatenate([lens, lens]))
+
+    return SamplerEngine(schedule, eps=Partial(jax.jit(eps_cond), params),
+                         eps_stacked=Partial(jax.jit(eps_stacked), params),
+                         eval_dtype=eval_dtype)
 
 
 def require_dit_for_cfg(ap, arch: str, cfg_scale: float) -> str:

@@ -28,7 +28,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..configs.registry import get_config
-from ..data.synthetic import TokenStream, class_ids, stub_embeds
+from ..data.synthetic import TokenStream, caption, class_ids, stub_embeds
 from ..models import api
 from .compile_cache import enable_compile_cache
 
@@ -252,9 +252,17 @@ def serve_diffusion(arch: str, *, reduced=True, batch=4, nfe=10, order=3,
         probe = QualityProbe(
             build_reference_fn(ref_engine, spec, ref_nfe=probe_ref_nfe),
             probe_fraction)
+    if cfg.caption_dim:
+        # an idle slot holds a one-token empty caption; its output is never
+        # read
+        extras_init = {"caption": np.zeros((cfg.caption_tokens,
+                                            cfg.caption_dim), np.float32),
+                       "caption_len": 1}
+    else:
+        extras_init = {"class_ids": NULL_CLASS_ID}
     sched = SlotScheduler(program, batch,
                           (cfg.patch_tokens, cfg.latent_dim),
-                          extras_init={"class_ids": NULL_CLASS_ID},
+                          extras_init=extras_init,
                           pipeline_depth=pipeline_depth,
                           tracer=tracer, probe=probe,
                           resilience=resilience, faults=faults)
@@ -272,7 +280,11 @@ def serve_diffusion(arch: str, *, reduced=True, batch=4, nfe=10, order=3,
         # (trace requests may carry their own tags)
         if tier_names is not None and r.tier is None:
             r.tier = tier_names[r.rid % len(tier_names)]
-        if r.extras is None or "class_ids" not in r.extras:
+        if cfg.caption_dim and "caption" not in (r.extras or {}):
+            emb, n = caption(r.seed, cfg.caption_tokens, cfg.caption_dim)
+            r.extras = {**(r.extras or {}), "caption": emb,
+                        "caption_len": n}
+        elif not cfg.caption_dim and "class_ids" not in (r.extras or {}):
             r.extras = {**(r.extras or {}),
                         "class_ids": int(class_ids(1, seed=r.seed)[0])}
     snap0 = sched.registry.snapshot()

@@ -70,7 +70,8 @@ def init_params(cfg: ModelConfig, rng):
     fam = cfg.family
     k1, k2, k3 = jax.random.split(rng, 3)
     if fam == "dit":
-        return {"backbone": init_dit(cfg, k1, num_classes=1000)}
+        return {"backbone": init_dit(
+            cfg, k1, num_classes=0 if cfg.caption_dim else 1000)}
     if fam in ("dense", "moe"):
         p = {"backbone": transformer.init_lm(cfg, k1)}
     elif fam == "ssm":
@@ -156,7 +157,8 @@ def eps_network(cfg: ModelConfig) -> Callable:
     """(params, x_t (B,S,L), t, batch) -> eps-hat — what UniPC samples from."""
     if cfg.family == "dit":
         return lambda p, x_t, t, batch: dit_apply(
-            p["backbone"], cfg, x_t, t, batch.get("class_ids"))
+            p["backbone"], cfg, x_t, t, batch.get("class_ids"),
+            batch.get("caption"), batch.get("caption_len"))
     fwd = _backbone_forward(cfg)
 
     def f(params, x_t, t, batch):

@@ -241,8 +241,11 @@ def chunked_sdpa(q, k, v, *, causal, sliding_window=None, chunk=1024):
 
 def attention_apply(params, x, cfg, *, kv_src=None, causal=True, positions=None,
                     kv_positions=None, sliding_window=None, rope=True,
-                    tap=None):
-    """Full-sequence attention (training / prefill without cache)."""
+                    kv_lens=None, tap=None):
+    """Full-sequence attention (training / prefill without cache).
+    `kv_lens` (B,) masks each row's keys past its own length (a padded
+    caption under cross-attention); an output bias `bo` in `params` is
+    added after the output projection."""
     kv_src = x if kv_src is None else kv_src
     q, k, v = _proj_qkv(params, x, kv_src, cfg, tap=tap)
     if rope:
@@ -271,13 +274,16 @@ def attention_apply(params, x, cfg, *, kv_src=None, causal=True, positions=None,
         out = fa_ops.attention(
             q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
             v.transpose(0, 2, 1, 3), causal=causal, window=sliding_window,
-            backend=getattr(cfg, "attention_backend", None),
+            kv_lens=kv_lens, backend=getattr(cfg, "attention_backend", None),
         ).transpose(0, 2, 1, 3)
     B, S = x.shape[:2]
     out = out.reshape(B, S, cfg.num_heads * cfg.head_dim)
     if tap is not None:
         tap("wo", out)
-    return dense_apply(out, params["wo"], cfg)
+    out = dense_apply(out, params["wo"], cfg)
+    if "bo" in params:
+        out = out + params["bo"].astype(out.dtype)
+    return out
 
 
 def maybe_remat(body, cfg):

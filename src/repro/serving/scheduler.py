@@ -134,7 +134,9 @@ def _apply_admission(state, meta, g, extras,
     meta = jnp.where(mask[None, :], meta_new, meta)
     if uses_cfg:
         g = jnp.where(mask, g_new, g)
-    extras = {k: jnp.where(mask, ex_new[k], v) for k, v in extras.items()}
+    extras = {k: jnp.where(mask.reshape(mask.shape + (1,) * (v.ndim - 1)),
+                           ex_new[k], v)
+              for k, v in extras.items()}
     return state, meta, g, extras
 
 
@@ -281,14 +283,17 @@ class SlotScheduler:
         self.state = program.init_state(slots, self.sample_shape, dtype)
         self.meta = program.init_meta(slots)
         self.g = program.init_g(slots)
-        # per-slot model conditioning (e.g. class ids): one (slots,) array
-        # per key, seeded from extras_init and overwritten at admission from
-        # Request.extras — conditioning is per-REQUEST, never slot-positional.
-        # Explicit dtypes: AOT-compiled signatures must not drift weak->strong
+        # per-slot model conditioning: one (slots, *shape) array per key —
+        # a class id or caption length is a scalar a slot, a caption an
+        # array of its own shape — seeded from extras_init and overwritten
+        # at admission from Request.extras: conditioning is per-REQUEST,
+        # never slot-positional. Explicit dtypes: AOT-compiled signatures
+        # must not drift weak->strong
         def _col(v):
-            dt = (jnp.int32 if np.issubdtype(np.asarray(v).dtype, np.integer)
+            v = np.asarray(v)
+            dt = (jnp.int32 if np.issubdtype(v.dtype, np.integer)
                   else jnp.float32)
-            return jnp.full((slots,), v, dt)
+            return jnp.broadcast_to(jnp.asarray(v, dt), (slots,) + v.shape)
 
         self.extras = {k: _col(v) for k, v in (extras_init or {}).items()}
         self._extras_init = dict(extras_init or {})
@@ -390,6 +395,10 @@ class SlotScheduler:
                                         help="host ns blocked in device->host "
                                              "reads")
                            for s in SYNC_SITES}
+        self._m_extras_bytes = r.counter(
+            "serve_extras_bytes",
+            help="bytes of per-request conditioning (extras) the admission "
+                 "apply puts on the device")
         self._m_device_draws = r.counter(
             "serve_device_draws",
             help="admitted requests whose x_T the admission apply drew "
@@ -405,7 +414,7 @@ class SlotScheduler:
                         else lambda _nets, *args: step_override(*args))
         self._np_dtype = np.dtype(dtype)
         self._x_zero = jnp.zeros((slots,) + self.sample_shape, dtype)
-        self._extras_np = {k: np.asarray(v).dtype
+        self._extras_np = {k: (v.shape[1:], np.dtype(v.dtype))
                            for k, v in self.extras.items()}
 
     # -- queue / slots -------------------------------------------------------
@@ -619,10 +628,12 @@ class SlotScheduler:
             g_new[taken] = [float(r.cfg_scale) if r.cfg_scale is not None
                             else float(self.program.spec.cfg_scale or 0.0)
                             for r in reqs]
-        ex_new = {k: np.zeros(B, self._extras_np[k]) for k in self.extras}
+        ex_new = {k: np.zeros((B,) + shape, dt)
+                  for k, (shape, dt) in self._extras_np.items()}
         for k in ex_new:
             ex_new[k][taken] = [(r.extras or {}).get(k, self._extras_init[k])
                                 for r in reqs]
+        self._m_extras_bytes.inc(sum(a.nbytes for a in ex_new.values()))
         with phase("admit_apply", self.tracer):
             self.state, self.meta, self.g, self.extras = _apply_admission(
                 tuple(self.state), self.meta, self.g, self.extras,

@@ -173,3 +173,85 @@ def test_scan_fused_default_matches_jnp_path(vp, order, monkeypatch):
     fused_out = unipc_sample_scan(data, x_T, us, fused_update=True)
     np.testing.assert_allclose(np.asarray(fused_out), np.asarray(ref_out),
                                rtol=1e-5, atol=1e-5)
+
+
+# ---------------------------------------------------------------------------
+# per-row key lengths (cross-attention over a padded caption)
+# ---------------------------------------------------------------------------
+
+def _masked_softmax_attention(q, k, v, lens):
+    """The explicit oracle: scores of each row's keys past its length set to
+    -inf before a float32 softmax, at HIGHEST precision."""
+    s = jnp.einsum("bhqd,bhkd->bhqk", q, k,
+                   precision=jax.lax.Precision.HIGHEST) / np.sqrt(q.shape[-1])
+    ok = jnp.arange(k.shape[2])[None] < jnp.asarray(lens)[:, None]
+    s = jnp.where(ok[:, None, None, :], s, -jnp.inf)
+    return jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(s, axis=-1), v,
+                      precision=jax.lax.Precision.HIGHEST)
+
+
+@pytest.mark.parametrize("backend", ["interpret", "jnp"])
+@pytest.mark.parametrize("skv,lens", [
+    (120, [1, 57, 120]),          # 120 keys padded to one block of 128
+    (300, [128, 256, 300]),       # lengths ending on block edges
+    (300, [1, 129, 255]),         # live blocks 1, 2 and 2 of 3
+], ids=["caption-120", "block-edges", "skipped-blocks"])
+def test_flash_attention_per_row_key_length(backend, skv, lens):
+    """Each row attends to its own first lens[b] keys: Sq 256 against Skv
+    keys, rows of different lengths in one call. Tolerance: float32 sums in
+    another order (online softmax against one softmax), ~1e-6 here."""
+    ks = jax.random.split(jax.random.PRNGKey(11), 3)
+    B, H, Sq, D = len(lens), 2, 256, 72
+    q = jax.random.normal(ks[0], (B, H, Sq, D))
+    k = jax.random.normal(ks[1], (B, H, skv, D))
+    v = jax.random.normal(ks[2], (B, H, skv, D))
+    got = fa_ops.attention(q, k, v, causal=False, backend=backend,
+                           kv_lens=jnp.asarray(lens, jnp.int32))
+    want = _masked_softmax_attention(q, k, v, lens)
+    np.testing.assert_allclose(got, want, atol=2e-5, rtol=0)
+
+
+def test_flash_attention_keys_past_a_row_length_are_never_read():
+    """Keys past a row's length count for nothing: large garbage in the
+    rest of its last live block is masked, and NaN in the blocks wholly
+    past it never enters the sums (those tiles are skipped)."""
+    ks = jax.random.split(jax.random.PRNGKey(12), 3)
+    lens = jnp.array([5, 130], jnp.int32)
+    q = jax.random.normal(ks[0], (2, 2, 128, 64))
+    k = jax.random.normal(ks[1], (2, 2, 256, 64))
+    v = jax.random.normal(ks[2], (2, 2, 256, 64))
+    pos = jnp.arange(256)[None, None, :, None]
+    past = pos >= lens[:, None, None, None]
+    dead = pos >= 128 * (-(-lens // 128))[:, None, None, None]
+    bad = jnp.where(dead, jnp.nan, 1e3)
+    kn, vn = jnp.where(past, bad, k), jnp.where(past, bad, v)
+    got = fa_ops.attention(q, kn, vn, causal=False, backend="interpret",
+                           kv_lens=lens)
+    want = _masked_softmax_attention(q, k, v, lens)
+    assert np.isfinite(np.asarray(got)).all()
+    np.testing.assert_allclose(got, want, atol=2e-5, rtol=0)
+
+
+# sha-256 (first 32 hex digits) of the unmasked self call's output as the
+# kernel gave it before per-row key lengths existed, on the CPU backend
+SELF_CALL_PINS = {
+    ("float32", 64): "b902a1f1f3e392d186b50e35ef359a81",
+    ("float32", 256): "984b0358856658cfed0a31358c9d2589",
+    ("bfloat16", 64): "9530b3ff11c9dfbe8f6408f062540ddd",
+    ("bfloat16", 256): "f41c4f2be567405f560d79ecf8203b1e",
+}
+
+
+@pytest.mark.parametrize("dtype,S", sorted(SELF_CALL_PINS))
+def test_flash_attention_self_call_is_bit_identical(dtype, S):
+    """The self call (no key lengths) computes exactly what it did before
+    the per-row path was added: same grid, same tiles, same bits."""
+    import hashlib
+
+    ks = jax.random.split(jax.random.PRNGKey(6), 3)
+    q, k, v = (jax.random.normal(ki, (2, 3, S, 72)).astype(dtype)
+               for ki in ks)
+    out = np.asarray(fa_ops.attention(q, k, v, causal=False,
+                                      backend="interpret").astype("float32"))
+    digest = hashlib.sha256(np.ascontiguousarray(out).tobytes()).hexdigest()
+    assert digest[:32] == SELF_CALL_PINS[(dtype, S)]

@@ -425,3 +425,40 @@ def test_probe_fraction_and_max_probes_bound_the_replay(gaussian_dpm):
     sched0 = _sched(gaussian_dpm, probe=probe0)
     run_trace(sched0, _poisson_reqs(n=4))
     assert len(calls) == 2 and probe0.results == []
+
+
+def test_extras_bytes_count_every_admission_apply(gaussian_dpm, monkeypatch):
+    """serve_extras_bytes counts exactly the nbytes of the per-request
+    conditioning buffers each admission apply puts on the device: full
+    width (slots x shape) for every key, once per tick that admits."""
+    from repro.serving import scheduler as sched_mod
+
+    applied = []
+    real = sched_mod._apply_admission
+
+    def counting(*args, **kw):
+        applied.append(sum(np.asarray(a).nbytes for a in args[10].values()))
+        return real(*args, **kw)
+
+    monkeypatch.setattr(sched_mod, "_apply_admission", counting)
+    eng = SamplerEngine(gaussian_dpm.schedule, eps=_eps_jx(gaussian_dpm))
+    program = eng.build_step(EngineSpec(solver="unipc", order=3, nfe=7))
+
+    def ignore_extras(state, meta, g, extras):
+        return program.flight(program.nets, state, meta, g, None)
+
+    init = {"caption": np.zeros((5, 4), np.float32), "caption_len": 1}
+    sched = SlotScheduler(program, 3, (8,), extras_init=init,
+                          step_override=ignore_extras)
+    for rid in range(5):
+        sched.submit(Request(rid=rid, seed=rid, extras={
+            "caption": np.full((5, 4), rid, np.float32),
+            "caption_len": rid + 1}))
+    while sched.queue or sched.active:
+        sched.tick()
+    sched.flush()
+    # 3 slots: rids 0-2 admitted on the first tick, 3-4 once they finish
+    assert len(applied) == 2
+    assert applied == [3 * 5 * 4 * 4 + 3 * 4] * 2
+    got = sched.registry.snapshot()["serve_extras_bytes"]["value"]
+    assert got == sum(applied)

@@ -23,7 +23,9 @@ from repro.kernels.quant_matmul import ops as qm_ops
 from repro.kernels.unipc_update import ops as up_ops
 
 # arch -> (tokens T, d_model D, heads, latent_dim), the published widths
-WIDTHS = {"dit-i256": (256, 1152, 16, 32), "dit-cifar": (64, 384, 6, 48)}
+WIDTHS = {"dit-i256": (256, 1152, 16, 32), "dit-cifar": (64, 384, 6, 48),
+          "pixart-xl2-512": (1024, 1152, 16, 16)}
+CAPTION_TOKENS = 120   # PixArt-alpha's T5-XXL caption: the cross call's keys
 SLOTS = 4          # serving slots; guided evals stack 2 * SLOTS rows
 K = 5              # UniPC-3 combine terms
 
@@ -74,6 +76,12 @@ def _kernel_case(kernel, arch, dtype):
         return (lambda q, k, v: fa_ops.attention(q, k, v, causal=False,
                                                  backend="pallas"),
                 [qkv, qkv, qkv])
+    if kernel == "flash_attention_cross":
+        q = ((rows, H, T, D // H), dtype)
+        kv = ((rows, H, CAPTION_TOKENS, D // H), dtype)
+        return (lambda q, k, v, n: fa_ops.attention(
+            q, k, v, causal=False, kv_lens=n, backend="pallas"),
+            [q, kv, kv, ((rows,), jnp.int32)])
     if kernel == "quant_matmul_int8":
         return (lambda x, w, s: qm_ops.quant_matmul(x, w, s,
                                                     backend="pallas"),
@@ -89,7 +97,7 @@ CASES = [(k, a, d) for a in WIDTHS for k, d in [
     ("gate_residual", jnp.float32), ("gate_residual", jnp.bfloat16),
     ("flash_attention", jnp.float32), ("flash_attention", jnp.bfloat16),
     ("quant_matmul_int8", jnp.bfloat16),
-]]
+]] + [("flash_attention_cross", "pixart-xl2-512", jnp.bfloat16)]
 
 
 @pytest.mark.parametrize("kernel,arch,dtype", CASES,
