@@ -17,7 +17,7 @@ d. guided serving: dit-i256 through `launch.serve.serve_diffusion` (4 slots,
    request must complete with a finite latent, and the compiled step must
    hold the Pallas kernels (`tpu_custom_call`).
 e. unguided serving: the same for dit-cifar, unguided (the remainder tile of
-   the solver update at N = 3072 and attention's padding of T = 64).
+   the solver update at N = 3072 and attention's one 64-key block).
 
 Compile seconds, tick time and peak device memory are printed as smoke
 observations, not benchmark numbers. The last line of standard output is

@@ -5,9 +5,9 @@ and ref.py (pure-jnp oracle):
 
 * unipc_update    — fused multi-term solver state update (one HBM pass);
                     the scan sampler's default combine (DESIGN.md §4-§5)
-* flash_attention — blockwise online-softmax GQA attention (causal,
-                    non-causal, sliding-window), (128, 128) MXU-aligned
-                    tiles; the model-side attention in
+* flash_attention — blockwise GQA attention (causal, non-causal,
+                    sliding-window, per-row key lengths), tiles planned
+                    from the call's shapes; the model-side attention in
                     `models.layers.attention_apply` routes through its ops
                     wrapper (the fast-eval path, DESIGN.md §11)
 * adaln_modulate  — fused layernorm + adaLN-zero scale/shift and the gated

@@ -1,5 +1,7 @@
 """Per-kernel shape/dtype sweeps: Pallas (interpret mode) vs pure-jnp oracle."""
 
+import hashlib
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -214,7 +216,9 @@ def test_flash_attention_per_row_key_length(backend, skv, lens):
 def test_flash_attention_keys_past_a_row_length_are_never_read():
     """Keys past a row's length count for nothing: large garbage in the
     rest of its last live block is masked, and NaN in the blocks wholly
-    past it never enters the sums (those tiles are skipped)."""
+    past it never enters the sums (those tiles are skipped). The blocks are
+    pinned to 128: the plan would take all 256 keys in one block, and the
+    skipping holds wherever a call has several kv blocks."""
     ks = jax.random.split(jax.random.PRNGKey(12), 3)
     lens = jnp.array([5, 130], jnp.int32)
     q = jax.random.normal(ks[0], (2, 2, 128, 64))
@@ -226,32 +230,112 @@ def test_flash_attention_keys_past_a_row_length_are_never_read():
     bad = jnp.where(dead, jnp.nan, 1e3)
     kn, vn = jnp.where(past, bad, k), jnp.where(past, bad, v)
     got = fa_ops.attention(q, kn, vn, causal=False, backend="interpret",
-                           kv_lens=lens)
+                           kv_lens=lens, blk_q=128, blk_k=128)
     want = _masked_softmax_attention(q, k, v, lens)
     assert np.isfinite(np.asarray(got)).all()
     np.testing.assert_allclose(got, want, atol=2e-5, rtol=0)
 
 
-# sha-256 (first 32 hex digits) of the unmasked self call's output as the
-# kernel gave it before per-row key lengths existed, on the CPU backend
+# sha-256 (first 32 hex digits) of the unmasked self call's output on the
+# CPU backend, at the blocks `tile_plan` picks: one kv block holding every
+# key, q, k and v into the dots in their own dtype, float32 accumulation
 SELF_CALL_PINS = {
-    ("float32", 64): "b902a1f1f3e392d186b50e35ef359a81",
-    ("float32", 256): "984b0358856658cfed0a31358c9d2589",
-    ("bfloat16", 64): "9530b3ff11c9dfbe8f6408f062540ddd",
-    ("bfloat16", 256): "f41c4f2be567405f560d79ecf8203b1e",
+    ("float32", 64): "f89b7b441a1a6d090013a51e77024ee3",
+    ("float32", 256): "6d132f72fb2aa50e99aaa6c5985a36a9",
+    ("bfloat16", 64): "bcadf3ac1b822abaf274782b26fce4a4",
+    ("bfloat16", 256): "576d40ca11bdd21d2343308a60d514a5",
 }
+
+
+def _self_call(dtype, S, kv_lens=None):
+    """(q, k, v, the kernel's output as float32) of one self call."""
+    ks = jax.random.split(jax.random.PRNGKey(6), 3)
+    q, k, v = (jax.random.normal(ki, (2, 3, S, 72)).astype(dtype)
+               for ki in ks)
+    out = fa_ops.attention(q, k, v, causal=False, kv_lens=kv_lens,
+                           backend="interpret")
+    return q, k, v, np.asarray(out.astype("float32"))
+
+
+def _digest(out):
+    return hashlib.sha256(np.ascontiguousarray(out).tobytes()).hexdigest()
 
 
 @pytest.mark.parametrize("dtype,S", sorted(SELF_CALL_PINS))
 def test_flash_attention_self_call_is_bit_identical(dtype, S):
-    """The self call (no key lengths) computes exactly what it did before
-    the per-row path was added: same grid, same tiles, same bits."""
-    import hashlib
+    """The self call (no key lengths) computes the pinned bits at the
+    plan's blocks, and they agree with the oracle."""
+    q, k, v, out = _self_call(dtype, S)
+    assert _digest(out)[:32] == SELF_CALL_PINS[(dtype, S)]
+    want = np.asarray(fa_ref.attention(q, k, v, causal=False), np.float32)
+    tol = (0, 2e-5) if dtype == "float32" else (3e-2, 3e-2)
+    np.testing.assert_allclose(out, want, rtol=tol[0], atol=tol[1])
 
-    ks = jax.random.split(jax.random.PRNGKey(6), 3)
-    q, k, v = (jax.random.normal(ki, (2, 3, S, 72)).astype(dtype)
-               for ki in ks)
-    out = np.asarray(fa_ops.attention(q, k, v, causal=False,
-                                      backend="interpret").astype("float32"))
-    digest = hashlib.sha256(np.ascontiguousarray(out).tobytes()).hexdigest()
-    assert digest[:32] == SELF_CALL_PINS[(dtype, S)]
+
+@pytest.mark.parametrize("dtype,S", sorted(SELF_CALL_PINS))
+def test_flash_attention_full_key_lengths_match_self_call(dtype, S):
+    """Key lengths that all equal Skv give the self call's bits."""
+    *_, out = _self_call(dtype, S, kv_lens=jnp.full((2,), S, jnp.int32))
+    assert _digest(out)[:32] == SELF_CALL_PINS[(dtype, S)]
+
+
+# ---------------------------------------------------------------------------
+# the tile plan at the cells' shapes
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("B,H,Sq,Skv,D,causal,plan,steps", [
+    (16, 16, 256, 256, 72, False, (256, 256, 16), 16),
+    (32, 6, 64, 64, 64, False, (64, 64, 6), 32),
+    (16, 16, 1024, 1024, 72, False, (1024, 1024, 2), 128),
+    (16, 16, 1024, 120, 72, False, (1024, 128, 4), 64),
+    (1, 8, 8192, 8192, 128, True, (512, 512, 1), 8 * 16 * 16),
+], ids=["i256", "s4", "pixart-self", "pixart-cross", "long-causal"])
+def test_tile_plan_at_cell_shapes(B, H, Sq, Skv, D, causal, plan, steps):
+    """Keys that fit take one kv block rounded up to the bf16 sublane
+    multiple and no further; a long causal call keeps several kv blocks
+    within the step's VMEM budget. The grid steps are those PERF.md
+    records for each cell."""
+    from repro.kernels.flash_attention import kernel as fa_kernel
+
+    blk_q, blk_k, heads = fa_ops.tile_plan(Sq, Skv, D, H, H, jnp.bfloat16,
+                                           causal, None)
+    assert (blk_q, blk_k, heads) == plan
+    nk = -(-Skv // blk_k)
+    if causal:
+        assert nk > 1
+        assert blk_q * blk_k <= fa_kernel.SCORES_PER_STEP
+        # q, k, v and out blocks of bf16, double-buffered
+        assert 2 * 2 * (blk_q + blk_k) * D * 2 <= fa_kernel.IO_BYTES
+    else:
+        assert nk == 1 and blk_k == -(-Skv // 16) * 16
+    assert B * H // heads * -(-Sq // blk_q) * nk == steps
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("case", ["s4-self", "cross-one-block"])
+def test_flash_attention_at_planned_blocks(case, dtype):
+    """Interpret mode against the oracle at the plan's own blocks: s4's 64
+    keys unpadded with its six heads in one step, and one 128-key block
+    of a 120-token caption with rows of lengths 1, 8, 57 and 120."""
+    if case == "s4-self":
+        shape_q, skv, lens = (2, 6, 64, 64), 64, None
+    else:
+        shape_q, skv, lens = (4, 2, 256, 72), 120, [1, 8, 57, 120]
+    B, H, Sq, D = shape_q
+    blk_q, blk_k, heads = fa_ops.tile_plan(Sq, skv, D, H, H, dtype, False,
+                                           None)
+    # the sublane multiple: 16 rows of bf16, 8 of float32
+    keys = {"s4-self": 64, "cross-one-block": 128 if dtype == "bfloat16"
+            else 120}[case]
+    assert (blk_q, blk_k) == (Sq, keys)
+    ks = jax.random.split(jax.random.PRNGKey(13), 3)
+    q = jax.random.normal(ks[0], shape_q).astype(dtype)
+    k = jax.random.normal(ks[1], (B, H, skv, D)).astype(dtype)
+    v = jax.random.normal(ks[2], (B, H, skv, D)).astype(dtype)
+    n = None if lens is None else jnp.asarray(lens, jnp.int32)
+    got = np.asarray(fa_ops.attention(q, k, v, causal=False, kv_lens=n,
+                                      backend="interpret"), np.float32)
+    want = np.asarray(fa_ref.attention(q, k, v, causal=False, kv_lens=n),
+                      np.float32)
+    tol = (0, 2e-5) if dtype == "float32" else (3e-2, 3e-2)
+    np.testing.assert_allclose(got, want, rtol=tol[0], atol=tol[1])

